@@ -6,5 +6,5 @@ bounded admission, dynamic batch sizing, per-request latency percentiles.
 """
 from repro.launch.serve import main
 
-main(["--arch", "deepseek-7b", "--requests", "6", "--prompt-len", "16",
-      "--max-new", "4"])
+main(["--arch", "deepseek-7b", "--reduced", "--requests", "6",
+      "--prompt-len", "16", "--max-new", "4"])

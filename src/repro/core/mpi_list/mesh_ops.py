@@ -20,13 +20,19 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
 def data_sharding(mesh, ndim: int):
+    """Rows over `data` (and `pod`), on an Auto-typed copy of `mesh`: XLA's
+    partitioner then inserts the cross-shard exchanges that reduce, scan
+    and group need.  Explicit axes (`jax.make_mesh`'s default) refuse a
+    strided slice or a gather across shards instead."""
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     axes = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-    return NamedSharding(mesh, P(axes, *([None] * (ndim - 1))))
+    return NamedSharding(auto, P(axes, *([None] * (ndim - 1))))
 
 
 def iterates(mesh, N: int) -> jax.Array:
@@ -76,10 +82,14 @@ def repartition(mesh, dfm):
 
 def group(mesh, dest: jax.Array, dfm):
     """Move row i to bucket dest[i] (stable within bucket): sort-by-key then
-    rebalance — the all-to-all exchange pattern of mpi-list.group."""
-    order = jnp.argsort(dest, stable=True)
-    out = jax.tree_util.tree_map(lambda v: jnp.take(v, order, axis=0), dfm)
-    return repartition(mesh, out)
+    rebalance — the all-to-all exchange pattern of mpi-list.group.  One
+    program, whose output lands in the contiguous-block partition."""
+    def sort_rows(dest, dfm):
+        order = jnp.argsort(dest, stable=True)
+        return jax.tree_util.tree_map(lambda v: jnp.take(v, order, axis=0),
+                                      dfm)
+    out = jax.tree_util.tree_map(lambda v: data_sharding(mesh, v.ndim), dfm)
+    return jax.jit(sort_rows, out_shardings=out)(dest, dfm)
 
 
 def collect(dfm):

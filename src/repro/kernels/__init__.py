@@ -7,6 +7,6 @@
   mamba2_ssd      chunked state-space-dual scan (Mamba2 inner loop)
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec VMEM tiling),
-ops.py (jit'd wrapper with interpret-mode fallback on CPU), and ref.py
+ops.py (jit'd wrapper; `interpret=True` only in the CPU tests), and ref.py
 (pure-jnp oracle used by the models and the allclose test sweeps).
 """

@@ -89,12 +89,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
     kernel = functools.partial(
         _flash_kernel, scale=scale, logit_softcap=logit_softcap,
         window=window, causal=causal, bq=bq, bk=bk, n_kv=T // bk)
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,4 +1,6 @@
-"""jit'd wrapper: Pallas on TPU (G==1), oracle fallback otherwise."""
+"""jit'd wrapper: the compiled Pallas kernel (n_groups == 1; grouped B/C
+raise); `interpret=True` runs it in the Pallas interpreter (CPU tests
+only)."""
 from __future__ import annotations
 
 from functools import partial
@@ -9,11 +11,8 @@ from repro.kernels.mamba2_ssd.kernel import ssd_pallas
 from repro.kernels.mamba2_ssd.ref import ssd_ref, ssd_sequential_ref
 
 
-@partial(jax.jit, static_argnames=("chunk", "force_interpret"))
-def ssd(xdt, dA, B_, C_, *, chunk: int = 64, force_interpret: bool = False):
-    if B_.shape[2] != 1:
-        return ssd_ref(xdt, dA, B_, C_, chunk=chunk)[0]
-    interpret = force_interpret or jax.default_backend() != "tpu"
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd(xdt, dA, B_, C_, *, chunk: int = 64, interpret: bool = False):
     return ssd_pallas(xdt, dA, B_, C_, chunk=chunk, interpret=interpret)
 
 

@@ -1,4 +1,5 @@
-"""jit'd wrapper with interpret fallback off-TPU."""
+"""jit'd wrapper: the compiled Pallas kernel; `interpret=True` runs it in
+the Pallas interpreter (CPU tests only)."""
 from __future__ import annotations
 
 from functools import partial
@@ -9,9 +10,8 @@ from repro.kernels.rwkv6_scan.kernel import wkv6_pallas
 from repro.kernels.rwkv6_scan.ref import wkv6_ref, wkv6_sequential_ref
 
 
-@partial(jax.jit, static_argnames=("chunk", "force_interpret"))
-def wkv6(r, k, v, logw, u, *, chunk: int = 32, force_interpret: bool = False):
-    interpret = force_interpret or jax.default_backend() != "tpu"
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def wkv6(r, k, v, logw, u, *, chunk: int = 32, interpret: bool = False):
     return wkv6_pallas(r, k, v, logw, u, chunk=chunk, interpret=interpret)
 
 

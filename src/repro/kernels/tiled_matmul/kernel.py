@@ -23,10 +23,14 @@ def _matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # A tile is (bk, bm): contract over the leading (k) dim => A^T @ B
+    # A tile is (bk, bm): contract over the leading (k) dim => A^T @ B.
+    # f32 operands ask for full f32 products (the paper's single-precision
+    # task), not the MXU's one bf16 pass
+    precision = (jax.lax.Precision.HIGHEST if a_ref.dtype == jnp.float32
+                 else None)
     acc_ref[...] += jax.lax.dot_general(
         a_ref[...], b_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
     def _flush():
@@ -44,12 +48,8 @@ def tiled_matmul_pallas(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
     kernel = functools.partial(_matmul_kernel, n_k=n_k)
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):  # older pallas naming
-        params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,4 +1,5 @@
-"""jit'd wrapper: Pallas on TPU, interpret-mode execution elsewhere."""
+"""jit'd wrapper: the compiled Pallas kernel; `interpret=True` runs it in
+the Pallas interpreter (CPU tests only)."""
 from __future__ import annotations
 
 from functools import partial
@@ -9,15 +10,10 @@ from repro.kernels.tiled_matmul.kernel import tiled_matmul_pallas
 from repro.kernels.tiled_matmul.ref import tiled_matmul_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "force_interpret"))
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def tiled_matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
-                 force_interpret: bool = False):
-    """C = A^T B via the Pallas kernel (interpret=True off-TPU)."""
-    interpret = force_interpret or not _on_tpu()
+                 interpret: bool = False):
+    """C = A^T B via the Pallas kernel."""
     return tiled_matmul_pallas(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
 
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 from pathlib import Path
 
@@ -49,6 +50,14 @@ campaign:
   tgt:
     metrics: "shard_{{n}}.jsonl"
 """
+
+
+def train_slots(nodes: int) -> int:
+    """How many `train` processes may run at once.  Each one takes every
+    chip JAX finds on the host, and a chip belongs to one process at a
+    time, so only a run held to the CPU (`JAX_PLATFORMS=cpu`, which the
+    popen'd trains inherit) runs more than one."""
+    return nodes if os.environ.get("JAX_PLATFORMS") == "cpu" else 1
 
 
 def summarize(directory: str, shards: int):
@@ -95,7 +104,8 @@ def main(argv=None):
     (Path(args.workdir) / "rules.yaml").write_text(rules)
     (Path(args.workdir) / "targets.yaml").write_text(targets)
 
-    pm = PMake(rules, targets, root=args.workdir, total_nodes=args.nodes)
+    pm = PMake(rules, targets, root=args.workdir,
+               total_nodes=train_slots(args.nodes))
     # EFT check: train tasks (with the summarize successor) outrank summarize
     stats = pm.run()
     print(f"[campaign] {stats}")

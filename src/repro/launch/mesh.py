@@ -21,7 +21,8 @@ def make_host_mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-# TPU v5e hardware constants (roofline denominators)
+# TPU v5e hardware constants (roofline denominators) for the pod dry-run
+# only; the benchmark replaces them with a table keyed by device_kind.
 PEAK_FLOPS_BF16 = 197e12          # per chip
 HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link

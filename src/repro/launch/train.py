@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint import ckpt
 from repro.configs import RunConfig, get_config
+from repro.launch import compile_cache
 from repro.data.pipeline import Pipeline
 from repro.models.common import Options, param_count
 from repro.models.model import build_model
@@ -66,6 +67,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg, model, rc = build(args)
     key = jax.random.PRNGKey(rc.seed)
     params = model.init(key)

@@ -29,33 +29,43 @@ def make_decode_step(model):
     return serve_step
 
 
-def greedy_generate(model, params, batch, max_new: int, cache_len: int):
-    """Small-scale example driver: prefill then greedy-decode max_new tokens."""
-    prefill = jax.jit(make_prefill_step(model))
-    decode = jax.jit(make_decode_step(model))
+def prefill_into_cache(model, params, batch, cache_len: int, prefill,
+                       decode):
+    """Run the prompts of `batch` through the jitted `prefill` step (the
+    recurrent families: token by token through `decode`, for exactness)
+    and return (first greedy token, a decode cache of `cache_len`
+    positions holding the prompts)."""
     B, S = batch["tokens"].shape
     if model.cfg.family in ("ssm", "hybrid"):
-        # recurrent state: run prefill token-by-token via decode for exactness
         cache = model.init_cache(B, cache_len)
         tok = batch["tokens"][:, 0]
         for t in range(S):
             tok, cache = decode(params, batch["tokens"][:, t],
                                 jnp.full((B,), t, jnp.int32), cache)
-    else:
-        tok, small_cache = prefill(params, batch)
-        cache = model.init_cache(B, cache_len)
+        return tok, cache
+    tok, small_cache = prefill(params, batch)
+    cache = model.init_cache(B, cache_len)
 
-        def splice(big, small):
-            difs = [i for i, (a, b) in enumerate(zip(big.shape, small.shape))
-                    if a != b]
-            if not difs:
-                return small.astype(big.dtype)
-            ax = difs[0]
-            idx = tuple(slice(None) if i != ax else slice(0, small.shape[ax])
-                        for i in range(big.ndim))
-            return big.at[idx].set(small.astype(big.dtype))
+    def splice(big, small):
+        difs = [i for i, (a, b) in enumerate(zip(big.shape, small.shape))
+                if a != b]
+        if not difs:
+            return small.astype(big.dtype)
+        ax = difs[0]
+        idx = tuple(slice(None) if i != ax else slice(0, small.shape[ax])
+                    for i in range(big.ndim))
+        return big.at[idx].set(small.astype(big.dtype))
 
-        cache = jax.tree_util.tree_map(splice, cache, small_cache)
+    return tok, jax.tree_util.tree_map(splice, cache, small_cache)
+
+
+def greedy_generate(model, params, batch, max_new: int, cache_len: int):
+    """Small-scale example driver: prefill then greedy-decode max_new tokens."""
+    prefill = jax.jit(make_prefill_step(model))
+    decode = jax.jit(make_decode_step(model))
+    B, S = batch["tokens"].shape
+    tok, cache = prefill_into_cache(model, params, batch, cache_len,
+                                    prefill, decode)
     out = [tok]
     for t in range(S, S + max_new - 1):
         tok, cache = decode(params, tok, jnp.full((B,), t, jnp.int32), cache)

@@ -98,6 +98,20 @@ sum:
         "payload-1\npayload-2\n"
 
 
+@pytest.mark.parametrize("platforms,slots", [(None, 1), ("tpu", 1),
+                                              ("cpu", 4)])
+def test_campaign_runs_one_train_per_chip(monkeypatch, platforms, slots):
+    """Each popen'd train takes every chip on its host: unless JAX is held
+    to the CPU, the campaign runs them one at a time whatever --nodes
+    says."""
+    from repro.launch.campaign import train_slots
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    assert train_slots(4) == slots
+
+
 def test_mpilist_is_the_data_pipeline():
     """The training pipeline is an mpi-list program: verify its batches
     flow through a real train step without NaNs."""
