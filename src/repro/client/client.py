@@ -26,7 +26,7 @@ from repro.client.futures import (_CANCELLED, _DONE, CancelledError,
 from repro.core.engine.comm.serialize import RemoteValue, Ref, dumps_call
 from repro.core.engine.executor import Engine, EngineReport
 from repro.core.engine.model import CREATED, FAILED, WorkerCrash, next_seq
-from repro.core.engine.tracing import OverheadReport, TraceRecorder
+from repro.core.engine.tracing import OverheadReport, TraceRecorder, span
 
 SCHEDULERS = ("dwork", "pmake", "mpi_list")
 
@@ -181,47 +181,48 @@ class Client:
         NOT forwarded to `fn` — to call a function with a same-named
         keyword, wrap it: `c.submit(functools.partial(fn, priority=3),
         x)`."""
-        self._check_open()
-        name = key if key is not None else \
-            f"{getattr(fn, '__name__', 'task')}-{next_seq()}"
-        fdeps = [a for a in args if isinstance(a, Future)]
-        if kwargs:
-            fdeps += [v for v in kwargs.values() if isinstance(v, Future)]
-        extra = []
-        for d in deps:
-            (fdeps if isinstance(d, Future) else extra).append(d)
-        dep_names = self._lift_deps(fdeps, extra)
-        if dep_names is None:           # a dependency already failed
-            return self._fail_fast(name, fdeps)
-        fut = Future(self, name)
-        engine_kw = {}
-        if tenant is not None:
-            engine_kw["meta"] = {"tenant": tenant}
-        if self.engine.transport == "proc":
-            # the task runs in another PROCESS: pack (fn, args, kwargs)
-            # with cloudpickle NOW — an unpicklable callable raises
-            # SerializationError here, naming the task, instead of
-            # hanging a worker.  Done-future arguments inline their
-            # value; pending ones ride as `Ref` placeholders the worker
-            # resolves from its local cache or a Fetch round-trip.  The
-            # `_make_call` wrapper (which captures the unpicklable
-            # Future) never crosses the boundary.
-            meta = dict(engine_kw.get("meta") or {})
-            meta["__call__"] = _proc_call_payload(name, fn, args, kwargs)
-            engine_kw["meta"] = meta
-            return self._submit(fut, fn=None, deps=dep_names,
-                                priority=priority,
+        with span("client.submit"):
+            self._check_open()
+            name = key if key is not None else \
+                f"{getattr(fn, '__name__', 'task')}-{next_seq()}"
+            fdeps = [a for a in args if isinstance(a, Future)]
+            if kwargs:
+                fdeps += [v for v in kwargs.values() if isinstance(v, Future)]
+            extra = []
+            for d in deps:
+                (fdeps if isinstance(d, Future) else extra).append(d)
+            dep_names = self._lift_deps(fdeps, extra)
+            if dep_names is None:           # a dependency already failed
+                return self._fail_fast(name, fdeps)
+            fut = Future(self, name)
+            engine_kw = {}
+            if tenant is not None:
+                engine_kw["meta"] = {"tenant": tenant}
+            if self.engine.transport == "proc":
+                # the task runs in another PROCESS: pack (fn, args, kwargs)
+                # with cloudpickle NOW — an unpicklable callable raises
+                # SerializationError here, naming the task, instead of
+                # hanging a worker.  Done-future arguments inline their
+                # value; pending ones ride as `Ref` placeholders the worker
+                # resolves from its local cache or a Fetch round-trip.  The
+                # `_make_call` wrapper (which captures the unpicklable
+                # Future) never crosses the boundary.
+                meta = dict(engine_kw.get("meta") or {})
+                meta["__call__"] = _proc_call_payload(name, fn, args, kwargs)
+                engine_kw["meta"] = meta
+                return self._submit(fut, fn=None, deps=dep_names,
+                                    priority=priority,
+                                    slots=max(int(slots), 1), retry=retry,
+                                    **engine_kw)
+            if not all(d.done() for d in fdeps):
+                # the wrapper will _peek a producer mid-run, so futures must
+                # resolve live (batch run() otherwise defers resolution to
+                # the final report and keeps the raw dispatch hot path)
+                self._live_results_needed = True
+            return self._submit(fut, fn=_make_call(fut, fn, args, kwargs),
+                                deps=dep_names, priority=priority,
                                 slots=max(int(slots), 1), retry=retry,
                                 **engine_kw)
-        if not all(d.done() for d in fdeps):
-            # the wrapper will _peek a producer mid-run, so futures must
-            # resolve live (batch run() otherwise defers resolution to
-            # the final report and keeps the raw dispatch hot path)
-            self._live_results_needed = True
-        return self._submit(fut, fn=_make_call(fut, fn, args, kwargs),
-                            deps=dep_names, priority=priority,
-                            slots=max(int(slots), 1), retry=retry,
-                            **engine_kw)
 
     def submit_task(self, name: str, *, deps=(), meta: Optional[dict] = None,
                     priority: float = 0.0, slots: int = 1,
@@ -360,20 +361,21 @@ class Client:
         fut = self._futures.pop(name, None)
         if fut is not None:
             self._futures_resolved += 1
-            if ok:
-                fut._resolve(state=_DONE, value=res.value, record=res)
-            elif error == "cancelled" and res is None:
-                fut._resolve(state=_CANCELLED)
-            elif fut._pending_exc is not None:
-                fut._resolve(state=_DONE, exception=fut._pending_exc,
-                             record=res)
-            elif res is None:
-                # never executed: poisoned upstream / failed at submit
-                fut._resolve(state=_DONE,
-                             exception=DependencyFailed(f"{name}: {error}"))
-            else:
-                fut._resolve(state=_DONE,
-                             exception=TaskFailed(f"{name}: {error}"))
+            with span("client.resolve"):
+                if ok:
+                    fut._resolve(state=_DONE, value=res.value, record=res)
+                elif error == "cancelled" and res is None:
+                    fut._resolve(state=_CANCELLED)
+                elif fut._pending_exc is not None:
+                    fut._resolve(state=_DONE, exception=fut._pending_exc,
+                                 record=res)
+                elif res is None:
+                    # never executed: poisoned upstream / failed at submit
+                    fut._resolve(state=_DONE, exception=DependencyFailed(
+                        f"{name}: {error}"))
+                else:
+                    fut._resolve(state=_DONE,
+                                 exception=TaskFailed(f"{name}: {error}"))
         self._resolved += 1
         if self._prune_every and self._resolved % self._prune_every == 0:
             self._pruned_any = True

@@ -71,7 +71,7 @@ from repro.core.engine.model import (CANCELLED, COMPLETED, CREATED, FAILED,
                                      RUN_START, STOLEN, WORKER_DEAD, XFER,
                                      EngineTask, RetryPolicy, TaskResult,
                                      WorkerCrash)
-from repro.core.engine.tracing import OverheadReport, TraceRecorder
+from repro.core.engine.tracing import OverheadReport, TraceRecorder, span
 
 # transport families live in the comm registry (repro.core.engine.comm);
 # this tuple stays as the public "what can I pass" surface
@@ -398,11 +398,12 @@ class Engine:
             notify(*note)
 
     def _on_terminal(self, name: str):
-        if self.resident:
-            with self._cond:
+        with span("engine.notify"):
+            if self.resident:
+                with self._cond:
+                    self._on_terminal_unlocked(name)
+            else:
                 self._on_terminal_unlocked(name)
-        else:
-            self._on_terminal_unlocked(name)
 
     def _on_terminal_unlocked(self, name: str):
         if name not in self._succs:
@@ -427,14 +428,15 @@ class Engine:
         the engine)."""
         notify = self.on_result
         pending: list = []
-        with self._cond:
-            n = self._note_locked(name, ok, res, error,
-                                  pending, notify is not None)
-            self._inflight -= n
-            if self._inflight <= 0:
-                self._cond.notify_all()
-        for note in pending:
-            notify(*note)
+        with span("engine.notify"):
+            with self._cond:
+                n = self._note_locked(name, ok, res, error,
+                                      pending, notify is not None)
+                self._inflight -= n
+                if self._inflight <= 0:
+                    self._cond.notify_all()
+            for note in pending:
+                notify(*note)
 
     def _note_terminal_many(self, batch: list):
         """Batched `_note_terminal` + successor readying: ONE lock hold
@@ -445,17 +447,19 @@ class Engine:
         notify = self.on_result
         want = notify is not None
         pending: list = []
-        with self._cond:
-            n = 0
-            for name, ok, res in batch:
-                if ok:
-                    self._on_terminal_unlocked(name)
-                n += self._note_locked(name, ok, res, None, pending, want)
-            self._inflight -= n
-            if self._inflight <= 0:
-                self._cond.notify_all()
-        for note in pending:
-            notify(*note)
+        with span("engine.notify"):
+            with self._cond:
+                n = 0
+                for name, ok, res in batch:
+                    if ok:
+                        self._on_terminal_unlocked(name)
+                    n += self._note_locked(name, ok, res, None, pending,
+                                           want)
+                self._inflight -= n
+                if self._inflight <= 0:
+                    self._cond.notify_all()
+            for note in pending:
+                notify(*note)
 
     def _note_locked(self, name: str, ok: bool, res, error,
                      pending: list, want: bool) -> int:
@@ -800,36 +804,38 @@ class Engine:
     def _run_one(self, exec_fn, name: str, meta: dict,
                  worker: str) -> TaskResult:
         tracer = self.tracer
-        tracer.emit4(RUN_START, name, worker)
-        t0 = time.perf_counter()
-        ok, value, err, crashed = True, None, None, False
-        try:
-            if self._pass_worker:
-                out = exec_fn(name, meta, worker)
+        with span("engine.run", task=name):
+            tracer.emit4(RUN_START, name, worker)
+            t0 = time.perf_counter()
+            ok, value, err, crashed = True, None, None, False
+            try:
+                if self._pass_worker:
+                    out = exec_fn(name, meta, worker)
+                else:
+                    out = exec_fn(name, meta)
+                if isinstance(out, tuple):
+                    ok, value = bool(out[0]), out[1]
+                elif out is None:
+                    ok = True
+                elif isinstance(out, bool):
+                    ok = out
+                else:
+                    ok, value = True, out
+            except WorkerCrash as e:
+                ok, err, crashed = False, repr(e), True
+            except Exception as e:                    # noqa: BLE001
+                ok, err = False, repr(e)
+            t1 = time.perf_counter()
+            virtual = 0.0
+            if self.faults is not None:
+                virtual = self.faults.delay_s(name, worker)
+                if self.faults.force_fail(name, worker,
+                                          self._attempts.get(name, 0)):
+                    ok, err = False, err or "injected fault"
+                tracer.emit(RUN_END, task=name, worker=worker,
+                            virtual_s=virtual)
             else:
-                out = exec_fn(name, meta)
-            if isinstance(out, tuple):
-                ok, value = bool(out[0]), out[1]
-            elif out is None:
-                ok = True
-            elif isinstance(out, bool):
-                ok = out
-            else:
-                ok, value = True, out
-        except WorkerCrash as e:
-            ok, err, crashed = False, repr(e), True
-        except Exception as e:                        # noqa: BLE001
-            ok, err = False, repr(e)
-        t1 = time.perf_counter()
-        virtual = 0.0
-        if self.faults is not None:
-            virtual = self.faults.delay_s(name, worker)
-            if self.faults.force_fail(name, worker,
-                                      self._attempts.get(name, 0)):
-                ok, err = False, err or "injected fault"
-            tracer.emit(RUN_END, task=name, worker=worker, virtual_s=virtual)
-        else:
-            tracer.emit4(RUN_END, name, worker)
+                tracer.emit4(RUN_END, name, worker)
         return TaskResult(task=name, ok=ok, worker=worker, t_start=t0,
                           t_end=t1, value=value, error=err,
                           virtual_s=virtual, crashed=crashed)
@@ -975,295 +981,88 @@ class Engine:
 
         try:
             while True:
-                rounds += 1
-                progress = False
-                backoff_wait = False
-                stopping = not resident or self._stop
-                # 0) resident: abort / membership commands / live retuning
-                if resident:
-                    if self._abort:
-                        break
-                    if self._mailbox:
-                        self._ingest_mailbox()
-                    if self._commands:
-                        with self._cond:
-                            cmds = list(self._commands)
-                            self._commands.clear()
-                        for cmd, w in cmds:
-                            if cmd == "add":
-                                if w in steals and w not in dead:
-                                    continue            # already live
-                                if w in dead:
-                                    # a recovered node rejoining under its
-                                    # old id: revive with a clean slate —
-                                    # only copies still in flight from the
-                                    # old incarnation stay attributed
-                                    dead.discard(w)
-                                    done_flag[w] = False
-                                    finished[w] = []
-                                    outstanding[w] = sum(
-                                        1 for r in running.values()
-                                        if r["worker"] == w)
-                                else:
-                                    alive.append(w)
-                                    steals[w] = 0
-                                    done_flag[w] = False
-                                    outstanding[w] = 0
-                                    finished[w] = []
-                                wstats.setdefault(w, [0, 0.0])
-                                self._live = len(alive) - len(dead)
-                                peak_workers = max(peak_workers, len(alive))
-                            elif cmd == "lose" and w in steals \
-                                    and w not in dead:
-                                bury(w, announce=True, reason="lose")
-                        n_alive = max(len(alive), 1)
-                    # steal_n is re-read every round so membership-aware
-                    # batching (elastic: pick_batch_size on remesh) applies
-                    # without restarting the loop
-                    steal_n = max(int(self.steal_n), 1)
-                    pending_limit = n_alive * steal_n + capacity
-                    epoch0 = self._epoch
-                    steal_ok = (stopping or epoch0 != quiet_epoch
-                                or rounds % IDLE_PROBE_ROUNDS == 0)
-                else:
-                    steal_ok = True
-                # 1) reap finished thread-pool tasks into per-worker batches
-                if running:
-                    for name in [n for n, r in running.items()
-                                 if r["fut"].done()]:
-                        rec = running.pop(name)
-                        free += rec["slots"]
-                        progress = True
-                        try_launch = True
-                        w = rec["worker"]
-                        if w in dead:
-                            continue  # lost completion: requeued via Exit
-                        res: TaskResult = rec["fut"].result()
-                        if res.crashed:
-                            bury(w, announce=True, crash=True)
-                            continue
-                        outstanding[w] -= 1
-                        st = wstats[w]
-                        if not res.ok:
-                            delay = retry_delay(name, res)
-                            if delay is not None:
-                                # transient: the worker keeps its
-                                # assignment; re-enqueue after backoff
-                                st[1] += res.t_end - res.t_start
-                                outstanding[w] += 1
-                                schedule_retry(name, rec["meta"], w, delay)
-                                continue
-                        st[0] += 1
-                        st[1] += res.t_end - res.t_start
-                        if not res.ok:
-                            self.exec_failed += 1
-                        if record_results:
-                            results[name] = res
-                        if note_terminal:
-                            note_terminal(name, res.ok, res)
-                        finished[w].append((name, res.ok))
-                        emit(COMPLETED if res.ok else FAILED, task=name,
-                             worker=w, error=res.error)
-                        if res.ok:  # failed tasks never ready their succs
-                            on_terminal(name)
-                # 2) complete+steal — one RPC flushes a worker's finished
-                # batch AND steals its next one (Fig. 2 batch-then-drain);
-                # a worker steals only while it holds fewer than steal_n
-                # outstanding tasks; rotation keeps the order fair
-                if n_alive == 1:
-                    rotation = alive
-                else:
-                    start = rounds % n_alive
-                    rotation = alive[start:] + alive[:start]
-                for w in rotation:
-                    if w in dead:
-                        continue
-                    batch = finished[w]
-                    want_steal = (steal_ok
-                                  and not done_flag[w]
-                                  and outstanding[w] < steal_n
-                                  and n_pending < pending_limit)
-                    if not batch and not want_steal:
-                        continue
-                    got = complete_steal(w, batch,
-                                         steal_n if want_steal else 0)
-                    if batch:
-                        finished[w] = []
-                        progress = True
-                    if not want_steal:
-                        continue
-                    if got == DONE:
-                        # resident pre-stop: the server saying "all done"
-                        # just means "idle right now" — more work may be
-                        # submitted, so keep the worker in the rotation
-                        if stopping:
-                            done_flag[w] = True
-                    elif got != EMPTY:
-                        steals[w] += len(got)
-                        accepted = []
-                        for name, meta in got:
-                            rec = running.get(name)
-                            if (name in pending_names
-                                    or (rec is not None
-                                        and rec["worker"] not in dead)):
-                                # duplicate steal after a lease-expiry
-                                # requeue while a LIVE copy is still held
-                                # (pending or in flight): the copy's
-                                # Complete clears every stale assignment
-                                # server-side, so just drop it.  A copy
-                                # held only by a DEAD worker is accepted —
-                                # its completion was discarded, so this
-                                # re-steal is the only way forward.
-                                continue
-                            prior = results.get(name)
-                            if prior is not None or name in terminal_seen:
-                                # already terminal engine-side: a stale
-                                # requeue duplicate with no live copy, or
-                                # a pruned name a later dep re-declared as
-                                # a server stub — report its terminal
-                                # state instead of dropping it, so the
-                                # server's join accounting (and any
-                                # dependents) can move.  Never re-execute.
-                                ok_prior = (prior.ok if prior is not None
-                                            else name not in self._failed)
-                                finished[w].append((name, ok_prior))
-                                progress = True
-                                continue
-                            accepted.append((name, meta))
-                        if not accepted:
-                            continue
-                        progress = True
-                        # drain a batch inline ONLY when nothing in it (or
-                        # already pending) carries a priority — otherwise a
-                        # prio-0 item would run before a higher-priority
-                        # one later in the same batch/heap
-                        drain = fast_drain and not heap and all(
-                            priority_of(name, meta) == 0.0
-                            for name, meta in accepted)
-                        if drain:
-                            # with terminal accounting on, bookkeeping is
-                            # batched: ONE lock hold (note_many) for the
-                            # whole drained batch, amortizing the
-                            # client-thread lock ping-pong over steal_n
-                            notes = [] if note_terminal is not None \
-                                else None
-                            st = wstats[w]
-                            for name, meta in accepted:
-                                # steal order == seq order: complete rides
-                                # on this worker's next CompleteSteal
-                                emit4(STOLEN, name, w)
-                                res = run_one(exec_fn, name, meta, w)
-                                if res.crashed:
-                                    # the rest of the batch is still
-                                    # assigned server-side: Exit recycles
-                                    # it with the in-flight task
-                                    bury(w, announce=True, crash=True)
-                                    break
-                                if not res.ok:
-                                    delay = retry_delay(name, res)
-                                    if delay is not None:
-                                        # the fast path never counted
-                                        # this steal in outstanding: the
-                                        # heap re-enqueue must
-                                        st[1] += res.t_end - res.t_start
-                                        outstanding[w] += 1
-                                        schedule_retry(name, meta, w,
-                                                       delay)
-                                        continue
-                                st[0] += 1
-                                st[1] += res.t_end - res.t_start
-                                if record_results:
-                                    results[name] = res
-                                finished[w].append((name, res.ok))
-                                if notes is not None:
-                                    notes.append((name, res.ok, res))
-                                if res.ok:
-                                    emit4(COMPLETED, name, w)
-                                    if notes is None:
-                                        on_terminal(name)
-                                else:
-                                    self.exec_failed += 1
-                                    emit(FAILED, task=name, worker=w,
-                                         error=res.error)
-                            if notes:
-                                note_many(notes)
-                            continue
-                        for name, meta in accepted:
-                            emit4(STOLEN, name, w)
-                            pending_names.add(name)
-                            outstanding[w] += 1
-                            seq += 1
-                            heappush(heap, (
-                                -priority_of(name, meta), seq,
-                                {"name": name, "meta": meta, "worker": w,
-                                 "slots": self._slots_of(name, meta)}))
-                            n_pending += 1
-                        try_launch = True
-                # resident idle gate: a fully quiet round (no completions,
-                # no steals served) arms the backoff until the epoch moves
-                if resident and not stopping and not progress and steal_ok:
-                    quiet_epoch = epoch0
-                # 3) fault injection: worker deaths (between steal & launch,
-                #    so a dying worker holds stolen-but-unstarted tasks)
-                if faults is not None:
-                    for w in alive:
-                        if w in dead:
-                            continue
-                        if faults.should_die(w, steals[w]):
-                            silent = faults.dies_silently(w)
-                            # announced death: Exit recycles assignment;
-                            # silent death: heartbeat-lease expiry recycles
-                            bury(w, announce=not silent, silent=silent)
-                # 4) launch: greedy highest-priority-first into free slots
-                if heap and try_launch:
-                    try_launch = False
-                    held = []
-                    while heap:
-                        entry = heappop(heap)
-                        it = entry[2]
-                        name = it["name"]
-                        if it["worker"] in dead:      # late scrub
-                            pending_names.discard(name)
-                            n_pending -= 1
-                            continue
-                        t_ready = it.get("t_ready")
-                        if t_ready is not None \
-                                and t_ready > time.perf_counter():
-                            held.append(entry)    # retry backoff pending
-                            backoff_wait = True
-                            continue
-                        if name in running:
-                            # a dead worker's copy is still in flight;
-                            # wait for it to drain before re-launching
-                            held.append(entry)
-                            continue
-                        slots = min(it["slots"], capacity)
-                        if slots > free:
-                            held.append(entry)
-                            continue
-                        pending_names.discard(name)
-                        n_pending -= 1
-                        w = it["worker"]
-                        if inline:
-                            res = self._run_one(exec_fn, name, it["meta"], w)
+                with span("engine.round"):
+                    rounds += 1
+                    progress = False
+                    backoff_wait = False
+                    stopping = not resident or self._stop
+                    # 0) resident: abort / membership commands / live retuning
+                    if resident:
+                        if self._abort:
+                            break
+                        if self._mailbox:
+                            with span("engine.ingest"):
+                                self._ingest_mailbox()
+                        if self._commands:
+                            with self._cond:
+                                cmds = list(self._commands)
+                                self._commands.clear()
+                            for cmd, w in cmds:
+                                if cmd == "add":
+                                    if w in steals and w not in dead:
+                                        continue            # already live
+                                    if w in dead:
+                                        # a recovered node rejoining under its
+                                        # old id: revive with a clean slate —
+                                        # only copies still in flight from the
+                                        # old incarnation stay attributed
+                                        dead.discard(w)
+                                        done_flag[w] = False
+                                        finished[w] = []
+                                        outstanding[w] = sum(
+                                            1 for r in running.values()
+                                            if r["worker"] == w)
+                                    else:
+                                        alive.append(w)
+                                        steals[w] = 0
+                                        done_flag[w] = False
+                                        outstanding[w] = 0
+                                        finished[w] = []
+                                    wstats.setdefault(w, [0, 0.0])
+                                    self._live = len(alive) - len(dead)
+                                    peak_workers = max(peak_workers,
+                                                       len(alive))
+                                elif cmd == "lose" and w in steals \
+                                        and w not in dead:
+                                    bury(w, announce=True, reason="lose")
+                            n_alive = max(len(alive), 1)
+                        # steal_n is re-read every round so membership-aware
+                        # batching (elastic: pick_batch_size on remesh) applies
+                        # without restarting the loop
+                        steal_n = max(int(self.steal_n), 1)
+                        pending_limit = n_alive * steal_n + capacity
+                        epoch0 = self._epoch
+                        steal_ok = (stopping or epoch0 != quiet_epoch
+                                    or rounds % IDLE_PROBE_ROUNDS == 0)
+                    else:
+                        steal_ok = True
+                    # 1) reap finished thread-pool tasks into per-worker
+                    # batches
+                    if running:
+                        for name in [n for n, r in running.items()
+                                     if r["fut"].done()]:
+                            rec = running.pop(name)
+                            free += rec["slots"]
+                            progress = True
+                            try_launch = True
+                            w = rec["worker"]
+                            if w in dead:
+                                continue  # lost completion: requeued via Exit
+                            res: TaskResult = rec["fut"].result()
                             if res.crashed:
-                                # bury scrubs this worker's remaining heap
-                                # entries; `held` is re-checked next pass
                                 bury(w, announce=True, crash=True)
-                                progress = True
                                 continue
+                            outstanding[w] -= 1
+                            st = wstats[w]
                             if not res.ok:
                                 delay = retry_delay(name, res)
                                 if delay is not None:
-                                    # still held by w (outstanding not
-                                    # yet decremented): re-enqueue only
-                                    wstats[w][1] += res.t_end - res.t_start
-                                    schedule_retry(name, it["meta"], w,
-                                                   delay)
-                                    progress = True
+                                    # transient: the worker keeps its
+                                    # assignment; re-enqueue after backoff
+                                    st[1] += res.t_end - res.t_start
+                                    outstanding[w] += 1
+                                    schedule_retry(name, rec["meta"], w, delay)
                                     continue
-                            outstanding[w] -= 1
-                            st = wstats[w]
                             st[0] += 1
                             st[1] += res.t_end - res.t_start
                             if not res.ok:
@@ -1275,57 +1074,273 @@ class Engine:
                             finished[w].append((name, res.ok))
                             emit(COMPLETED if res.ok else FAILED, task=name,
                                  worker=w, error=res.error)
-                            if res.ok:
-                                self._on_terminal(name)
-                        else:
-                            free -= slots
-                            fut = pool.submit(self._run_one, exec_fn, name,
-                                              it["meta"], w)
-                            running[name] = {"worker": w, "fut": fut,
-                                             "slots": slots,
-                                             "meta": it["meta"]}
-                        progress = True
-                    for entry in held:
-                        heappush(heap, entry)
-                    if backoff_wait:
-                        # a held backoff entry needs another launch pass
-                        # once its deadline arrives, whatever else the
-                        # round did
+                            if res.ok:  # failed tasks never ready their succs
+                                on_terminal(name)
+                    # 2) complete+steal — one RPC flushes a worker's finished
+                    # batch AND steals its next one (Fig. 2 batch-then-drain);
+                    # a worker steals only while it holds fewer than steal_n
+                    # outstanding tasks; rotation keeps the order fair
+                    if n_alive == 1:
+                        rotation = alive
+                    else:
+                        start = rounds % n_alive
+                        rotation = alive[start:] + alive[:start]
+                    for w in rotation:
+                        if w in dead:
+                            continue
+                        batch = finished[w]
+                        want_steal = (steal_ok
+                                      and not done_flag[w]
+                                      and outstanding[w] < steal_n
+                                      and n_pending < pending_limit)
+                        if not batch and not want_steal:
+                            continue
+                        with span("engine.steal"):
+                            got = complete_steal(
+                                w, batch, steal_n if want_steal else 0)
+                        if batch:
+                            finished[w] = []
+                            progress = True
+                        if not want_steal:
+                            continue
+                        if got == DONE:
+                            # resident pre-stop: the server saying "all done"
+                            # just means "idle right now" — more work may be
+                            # submitted, so keep the worker in the rotation
+                            if stopping:
+                                done_flag[w] = True
+                        elif got != EMPTY:
+                            steals[w] += len(got)
+                            accepted = []
+                            for name, meta in got:
+                                rec = running.get(name)
+                                if (name in pending_names
+                                        or (rec is not None
+                                            and rec["worker"] not in dead)):
+                                    # duplicate steal after a lease-expiry
+                                    # requeue while a LIVE copy is still held
+                                    # (pending or in flight): the copy's
+                                    # Complete clears every stale assignment
+                                    # server-side, so just drop it.  A copy
+                                    # held only by a DEAD worker is accepted —
+                                    # its completion was discarded, so this
+                                    # re-steal is the only way forward.
+                                    continue
+                                prior = results.get(name)
+                                if prior is not None or name in terminal_seen:
+                                    # already terminal engine-side: a stale
+                                    # requeue duplicate with no live copy, or
+                                    # a pruned name a later dep re-declared as
+                                    # a server stub — report its terminal
+                                    # state instead of dropping it, so the
+                                    # server's join accounting (and any
+                                    # dependents) can move.  Never re-execute.
+                                    ok_prior = (prior.ok if prior is not None
+                                                else name not in self._failed)
+                                    finished[w].append((name, ok_prior))
+                                    progress = True
+                                    continue
+                                accepted.append((name, meta))
+                            if not accepted:
+                                continue
+                            progress = True
+                            # drain a batch inline ONLY when nothing in it (or
+                            # already pending) carries a priority — otherwise a
+                            # prio-0 item would run before a higher-priority
+                            # one later in the same batch/heap
+                            drain = fast_drain and not heap and all(
+                                priority_of(name, meta) == 0.0
+                                for name, meta in accepted)
+                            if drain:
+                                # with terminal accounting on, bookkeeping is
+                                # batched: ONE lock hold (note_many) for the
+                                # whole drained batch, amortizing the
+                                # client-thread lock ping-pong over steal_n
+                                notes = [] if note_terminal is not None \
+                                    else None
+                                st = wstats[w]
+                                for name, meta in accepted:
+                                    # steal order == seq order: complete rides
+                                    # on this worker's next CompleteSteal
+                                    emit4(STOLEN, name, w)
+                                    res = run_one(exec_fn, name, meta, w)
+                                    if res.crashed:
+                                        # the rest of the batch is still
+                                        # assigned server-side: Exit recycles
+                                        # it with the in-flight task
+                                        bury(w, announce=True, crash=True)
+                                        break
+                                    if not res.ok:
+                                        delay = retry_delay(name, res)
+                                        if delay is not None:
+                                            # the fast path never counted
+                                            # this steal in outstanding: the
+                                            # heap re-enqueue must
+                                            st[1] += res.t_end - res.t_start
+                                            outstanding[w] += 1
+                                            schedule_retry(name, meta, w,
+                                                           delay)
+                                            continue
+                                    st[0] += 1
+                                    st[1] += res.t_end - res.t_start
+                                    if record_results:
+                                        results[name] = res
+                                    finished[w].append((name, res.ok))
+                                    if notes is not None:
+                                        notes.append((name, res.ok, res))
+                                    if res.ok:
+                                        emit4(COMPLETED, name, w)
+                                        if notes is None:
+                                            on_terminal(name)
+                                    else:
+                                        self.exec_failed += 1
+                                        emit(FAILED, task=name, worker=w,
+                                             error=res.error)
+                                if notes:
+                                    note_many(notes)
+                                continue
+                            for name, meta in accepted:
+                                emit4(STOLEN, name, w)
+                                pending_names.add(name)
+                                outstanding[w] += 1
+                                seq += 1
+                                heappush(heap, (
+                                    -priority_of(name, meta), seq,
+                                    {"name": name, "meta": meta, "worker": w,
+                                     "slots": self._slots_of(name, meta)}))
+                                n_pending += 1
+                            try_launch = True
+                    # resident idle gate: a fully quiet round (no completions,
+                    # no steals served) arms the backoff until the epoch moves
+                    if resident and not stopping and not progress and steal_ok:
+                        quiet_epoch = epoch0
+                    # 3) fault injection: worker deaths (between steal &
+                    #    launch, so a dying worker holds stolen-but-unstarted
+                    #    tasks)
+                    if faults is not None:
+                        for w in alive:
+                            if w in dead:
+                                continue
+                            if faults.should_die(w, steals[w]):
+                                silent = faults.dies_silently(w)
+                                # announced death: Exit recycles assignment;
+                                # silent death: heartbeat-lease expiry recycles
+                                bury(w, announce=not silent, silent=silent)
+                    # 4) launch: greedy highest-priority-first into free slots
+                    if heap and try_launch:
+                        try_launch = False
+                        held = []
+                        while heap:
+                            entry = heappop(heap)
+                            it = entry[2]
+                            name = it["name"]
+                            if it["worker"] in dead:      # late scrub
+                                pending_names.discard(name)
+                                n_pending -= 1
+                                continue
+                            t_ready = it.get("t_ready")
+                            if t_ready is not None \
+                                    and t_ready > time.perf_counter():
+                                held.append(entry)    # retry backoff pending
+                                backoff_wait = True
+                                continue
+                            if name in running:
+                                # a dead worker's copy is still in flight;
+                                # wait for it to drain before re-launching
+                                held.append(entry)
+                                continue
+                            slots = min(it["slots"], capacity)
+                            if slots > free:
+                                held.append(entry)
+                                continue
+                            pending_names.discard(name)
+                            n_pending -= 1
+                            w = it["worker"]
+                            if inline:
+                                res = self._run_one(exec_fn, name,
+                                                    it["meta"], w)
+                                if res.crashed:
+                                    # bury scrubs this worker's remaining heap
+                                    # entries; `held` is re-checked next pass
+                                    bury(w, announce=True, crash=True)
+                                    progress = True
+                                    continue
+                                if not res.ok:
+                                    delay = retry_delay(name, res)
+                                    if delay is not None:
+                                        # still held by w (outstanding not
+                                        # yet decremented): re-enqueue only
+                                        wstats[w][1] += res.t_end - res.t_start
+                                        schedule_retry(name, it["meta"], w,
+                                                       delay)
+                                        progress = True
+                                        continue
+                                outstanding[w] -= 1
+                                st = wstats[w]
+                                st[0] += 1
+                                st[1] += res.t_end - res.t_start
+                                if not res.ok:
+                                    self.exec_failed += 1
+                                if record_results:
+                                    results[name] = res
+                                if note_terminal:
+                                    note_terminal(name, res.ok, res)
+                                finished[w].append((name, res.ok))
+                                emit(COMPLETED if res.ok else FAILED,
+                                     task=name, worker=w, error=res.error)
+                                if res.ok:
+                                    self._on_terminal(name)
+                            else:
+                                free -= slots
+                                fut = pool.submit(self._run_one, exec_fn, name,
+                                                  it["meta"], w)
+                                running[name] = {"worker": w, "fut": fut,
+                                                 "slots": slots,
+                                                 "meta": it["meta"]}
+                            progress = True
+                        for entry in held:
+                            heappush(heap, entry)
+                        if backoff_wait:
+                            # a held backoff entry needs another launch pass
+                            # once its deadline arrives, whatever else the
+                            # round did
+                            try_launch = True
+                    # 5) termination (batch mode, or resident after shutdown())
+                    if stopping and not running and not n_pending:
+                        live = [w for w in alive if w not in dead]
+                        if not live:
+                            # every worker died: unless one of them saw the
+                            # server's DONE first, work remains unserved —
+                            # that is a stall, not a clean finish.  A resident
+                            # pool counts its submitted universe instead (it
+                            # may legitimately stop with zero workers).
+                            if resident:
+                                stalled = (self._inflight > 0
+                                           or bool(self._mailbox))
+                            else:
+                                stalled = not any(done_flag.values())
+                            break
+                        if all(done_flag[w] for w in live) \
+                                and not any(finished[w] for w in live):
+                            break
+                    if progress:
+                        idle_rounds = 0
+                    elif backoff_wait:
+                        # retries waiting out their backoff are forward
+                        # progress in waiting, not a stall
+                        idle_rounds = 0
                         try_launch = True
-                # 5) termination (batch mode, or resident after shutdown())
-                if stopping and not running and not n_pending:
-                    live = [w for w in alive if w not in dead]
-                    if not live:
-                        # every worker died: unless one of them saw the
-                        # server's DONE first, work remains unserved —
-                        # that is a stall, not a clean finish.  A resident
-                        # pool counts its submitted universe instead (it
-                        # may legitimately stop with zero workers).
-                        if resident:
-                            stalled = (self._inflight > 0
-                                       or bool(self._mailbox))
-                        else:
-                            stalled = not any(done_flag.values())
-                        break
-                    if all(done_flag[w] for w in live) \
-                            and not any(finished[w] for w in live):
-                        break
-                if progress:
-                    idle_rounds = 0
-                elif backoff_wait:
-                    # retries waiting out their backoff are forward
-                    # progress in waiting, not a stall
-                    idle_rounds = 0
-                    try_launch = True
-                    time.sleep(self.poll)
-                elif not running:
-                    idle_rounds += 1
-                    if idle_rounds >= self.max_idle_rounds and stopping:
-                        stalled = True   # unresolvable (cycle / all leased)
-                        break
-                    time.sleep(self.poll)
-                else:
-                    time.sleep(self.poll)
+                        time.sleep(self.poll)
+                    elif not running:
+                        idle_rounds += 1
+                        if idle_rounds >= self.max_idle_rounds and stopping:
+                            # unresolvable (cycle / all leased)
+                            stalled = True
+                            break
+                        with span("engine.idle"):
+                            time.sleep(self.poll)
+                    else:
+                        time.sleep(self.poll)
         finally:
             if pool is not None:
                 pool.shutdown(wait=True)

@@ -22,12 +22,19 @@ quantities *measured from the running system* rather than modelled:
 `crosscheck()` compares an empirical value against the analytic scaling
 laws in `repro.core.metg` and reports whether they agree to within an
 order of magnitude — the engine's validation loop for the models.
+
+`span(name, **meta)` is the program's one profiler span: a
+`jax.profiler.TraceAnnotation` once the process has loaded JAX, so the
+engine, client, Frontend, serving and mesh layers appear on the device
+trace's clock (docs/observability.md, "Profiler spans").
 """
 from __future__ import annotations
 
 import json
+import sys
 import threading
 from collections import deque
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -37,6 +44,27 @@ from repro.core.engine.model import (BATCH_FORMED, COMPLETED, FAILED,
                                      RUN_START, STOLEN, XFER, TraceEvent,
                                      real_clock)
 from repro.core.metg import same_order
+
+
+_NO_SPAN = nullcontext()
+_annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def span(name: str, **meta):
+    """A profiler span over a `with` block: `jax.profiler.TraceAnnotation
+    (name, **meta)` when the process has loaded JAX, else a shared no-op
+    context.  Never imports JAX, so the engine, client and serving
+    modules stay importable without it.  `name` is a stable string;
+    identifiers go in `meta` (they land as the event's stats, so idle
+    gaps of the device trace group by name).  A name ending in `.idle`
+    marks a thread with nothing to do."""
+    global _annotation
+    if _annotation is None:
+        profiler = sys.modules.get("jax.profiler")
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+        if _annotation is None:
+            return _NO_SPAN
+    return _annotation(name, **meta)
 
 
 def percentile(sorted_vals: list, q: float) -> float:
@@ -498,14 +526,6 @@ class OverheadReport:
     def rpc_per_task_s(self) -> float:
         """Server-side handling time per terminal task (dwork RTT analog)."""
         return self.rpc_s / self.n_tasks if self.n_tasks else 0.0
-
-    @property
-    def queue_latency_per_task_s(self) -> float:
-        """Mean stolen -> run_start latency.  NOTE: includes time waiting
-        for a free slot (backlog), so it measures queue pressure, not pure
-        scheduler cost — use `rpc_per_task_s` / `per_task_overhead_s` for
-        overhead accounting."""
-        return self.dispatch_s / self.n_tasks if self.n_tasks else 0.0
 
     def empirical_metg(self) -> float:
         """Task duration at which measured overhead = compute (50% eff)."""

@@ -13,6 +13,10 @@ map onto jax-native constructs:
 
 This is the sense in which the framework's data-parallel inner loop *is*
 mpi-list: `train_step` = dfm.map(grad) . dfm.reduce(+).
+
+Each verb runs under a `mesh.<verb>` profiler span, and the function it
+jits is named `mesh_<verb>`, so JAX's dispatch span (`PjitFunction(...)`)
+and the program (`jit_mesh_<verb>`) name the verb in a device trace.
 """
 from __future__ import annotations
 
@@ -22,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
+
+from repro.core.engine.tracing import span
 
 
 def data_sharding(mesh, ndim: int):
@@ -46,8 +52,10 @@ def scatter(mesh, x) -> jax.Array:
 
 
 def dfm_map(mesh, f: Callable, dfm, *, donate: bool = False):
-    out_fn = jax.jit(jax.vmap(f), donate_argnums=(0,) if donate else ())
-    return out_fn(dfm)
+    def mesh_map(x):
+        return jax.vmap(f)(x)
+    with span("mesh.map"):
+        return jax.jit(mesh_map, donate_argnums=(0,) if donate else ())(dfm)
 
 
 def dfm_reduce(mesh, f_monoid: Callable, dfm):
@@ -60,36 +68,48 @@ def dfm_reduce(mesh, f_monoid: Callable, dfm):
         if n % 2:
             return f_monoid(pairwise(v[:-1]), v[-1])
         return pairwise(f_monoid(v[0::2], v[1::2]))
-    return jax.jit(lambda x: jax.tree_util.tree_map(pairwise, x))(dfm)
+
+    def mesh_reduce(x):
+        return jax.tree_util.tree_map(pairwise, x)
+    with span("mesh.reduce"):
+        return jax.jit(mesh_reduce)(dfm)
 
 
 def dfm_sum(mesh, dfm):
-    return jax.jit(lambda x: jax.tree_util.tree_map(
-        lambda v: jnp.sum(v, axis=0), x))(dfm)
+    def mesh_sum(x):
+        return jax.tree_util.tree_map(lambda v: jnp.sum(v, axis=0), x)
+    with span("mesh.sum"):
+        return jax.jit(mesh_sum)(dfm)
 
 
 def dfm_scan(mesh, f_assoc: Callable, dfm):
     """Inclusive prefix scan (cross-shard prefix exchange handled by XLA)."""
-    return jax.jit(lambda x: jax.tree_util.tree_map(
-        lambda v: jax.lax.associative_scan(f_assoc, v, axis=0), x))(dfm)
+    def mesh_scan(x):
+        return jax.tree_util.tree_map(
+            lambda v: jax.lax.associative_scan(f_assoc, v, axis=0), x)
+    with span("mesh.scan"):
+        return jax.jit(mesh_scan)(dfm)
 
 
 def repartition(mesh, dfm):
     """Rebalance to the canonical contiguous-block partition."""
-    return jax.tree_util.tree_map(
-        lambda v: jax.device_put(v, data_sharding(mesh, v.ndim)), dfm)
+    with span("mesh.repartition"):
+        return jax.tree_util.tree_map(
+            lambda v: jax.device_put(v, data_sharding(mesh, v.ndim)), dfm)
 
 
 def group(mesh, dest: jax.Array, dfm):
     """Move row i to bucket dest[i] (stable within bucket): sort-by-key then
     rebalance — the all-to-all exchange pattern of mpi-list.group.  One
     program, whose output lands in the contiguous-block partition."""
-    def sort_rows(dest, dfm):
+    def mesh_group(dest, dfm):
         order = jnp.argsort(dest, stable=True)
         return jax.tree_util.tree_map(lambda v: jnp.take(v, order, axis=0),
                                       dfm)
-    out = jax.tree_util.tree_map(lambda v: data_sharding(mesh, v.ndim), dfm)
-    return jax.jit(sort_rows, out_shardings=out)(dest, dfm)
+    with span("mesh.group"):
+        out = jax.tree_util.tree_map(lambda v: data_sharding(mesh, v.ndim),
+                                     dfm)
+        return jax.jit(mesh_group, out_shardings=out)(dest, dfm)
 
 
 def collect(dfm):

@@ -24,7 +24,7 @@ from typing import Callable, Optional
 from repro.core.engine.model import (BATCH_FORMED, REQ_DONE, REQ_ENQUEUED,
                                      REQ_REJECTED, REQ_TIMEOUT, WorkerCrash,
                                      next_seq)
-from repro.core.engine.tracing import LatencyReport, percentile
+from repro.core.engine.tracing import LatencyReport, percentile, span
 from repro.core.metg import METGModel, pick_batch_size
 
 
@@ -122,6 +122,7 @@ class Frontend:
         self.timeouts = 0              # queued past their deadline
         self._n_deadlines = 0          # queued requests carrying a deadline
         self.batches = 0
+        self._running = 0              # dispatched batches not yet returned
         # optional serving-metrics sink (repro.core.obs.ServingMetrics):
         # observed at response delivery, beside the REQ_DONE emit
         self.metrics = None
@@ -311,7 +312,9 @@ class Frontend:
                                        if r.deadline is not None)
                         dl = max(earliest - clock(), 1e-4)
                         wait = dl if wait is None else min(wait, dl)
-                    self._cond.wait(wait)
+                    idle = not n and self._running <= 0
+                    with span("frontend.idle" if idle else "frontend.wait"):
+                        self._cond.wait(wait)
                 self._force_flush = False
                 if not self._queue:
                     if self._closing:
@@ -357,19 +360,22 @@ class Frontend:
         tracer = self.engine.tracer
         self.batches += 1
         name = f"__batch{next_seq()}"
-        now = tracer.clock()
-        wait_s = now - batch[0].t_enqueue
-        tracer.emit(BATCH_FORMED, task=name, size=len(batch),
-                    wait_s=wait_s, target=self.target_batch(),
-                    depth=depth_after)
-        if self._monitoring:
-            with self._snap_lock:
-                self._w_batches += 1
-                self._w_batched += len(batch)
-                self._w_wait_s += wait_s
-                self._w_depths.append(depth_after)
-        reqs = tuple(batch)
-        self.engine.submit(name, fn=lambda: self._run_batch(reqs))
+        with span("frontend.dispatch", batch=name, size=len(batch)):
+            now = tracer.clock()
+            wait_s = now - batch[0].t_enqueue
+            tracer.emit(BATCH_FORMED, task=name, size=len(batch),
+                        wait_s=wait_s, target=self.target_batch(),
+                        depth=depth_after)
+            if self._monitoring:
+                with self._snap_lock:
+                    self._w_batches += 1
+                    self._w_batched += len(batch)
+                    self._w_wait_s += wait_s
+                    self._w_depths.append(depth_after)
+            reqs = tuple(batch)
+            self.engine.submit(name, fn=lambda: self._run_batch(reqs))
+            with self._cond:
+                self._running += 1
 
     def _run_batch(self, reqs: tuple):
         clock = self.engine.tracer.clock
@@ -382,7 +388,9 @@ class Frontend:
             err = repr(e)
             for r in reqs:
                 self._resolve(r, ok=False, error=err)
+            self._batch_returned()
             raise          # the batch task is marked failed, consistently
+        self._batch_returned()
         dt = clock() - t0
         a = self._ewma_alpha
         self._per_req_s = ((1 - a) * self._per_req_s
@@ -395,36 +403,45 @@ class Frontend:
                 self._resolve(r, ok=True, value=values)
         return True
 
+    def _batch_returned(self):
+        """A batch's body returned: with none left running, wake the
+        coalescer so an empty queue reads as idle from here on."""
+        with self._cond:
+            self._running -= 1
+            if self._running <= 0:
+                self._cond.notify_all()
+
     def _resolve(self, req: ServeRequest, *, ok: bool, value=None,
                  error: Optional[str] = None):
         if req._event.is_set():
             return             # re-execution after a requeue: deliver once
-        tracer = self.engine.tracer
-        req.value = value
-        req.ok = ok
-        req.error = error
-        req.t_done = tracer.clock()
-        latency_s = req.t_done - req.t_enqueue
-        if req.tenant is None:
-            tracer.emit(REQ_DONE, task=req.name, worker=None,
-                        latency_s=latency_s, ok=ok)
-        else:
-            tracer.emit(REQ_DONE, task=req.name, worker=None,
-                        latency_s=latency_s, ok=ok, tenant=req.tenant)
-        m = self.metrics
-        if m is not None:
-            m.observe_request(latency_s, ok, tenant=req.tenant)
-        if self._monitoring:
-            with self._snap_lock:
-                self._w_lats.append(latency_s)
-                if not ok:
-                    self._w_failed += 1
-                if req.tenant is not None:
-                    slot = self._w_tenant(req.tenant)
-                    slot[0].append(latency_s)
+        with span("frontend.resolve"):
+            tracer = self.engine.tracer
+            req.value = value
+            req.ok = ok
+            req.error = error
+            req.t_done = tracer.clock()
+            latency_s = req.t_done - req.t_enqueue
+            if req.tenant is None:
+                tracer.emit(REQ_DONE, task=req.name, worker=None,
+                            latency_s=latency_s, ok=ok)
+            else:
+                tracer.emit(REQ_DONE, task=req.name, worker=None,
+                            latency_s=latency_s, ok=ok, tenant=req.tenant)
+            m = self.metrics
+            if m is not None:
+                m.observe_request(latency_s, ok, tenant=req.tenant)
+            if self._monitoring:
+                with self._snap_lock:
+                    self._w_lats.append(latency_s)
                     if not ok:
-                        slot[1] += 1
-        req._event.set()
+                        self._w_failed += 1
+                    if req.tenant is not None:
+                        slot = self._w_tenant(req.tenant)
+                        slot[0].append(latency_s)
+                        if not ok:
+                            slot[1] += 1
+            req._event.set()
 
     def _w_tenant(self, tenant: str) -> list:
         """The window accumulator slot for one tenant: [lats, failed,

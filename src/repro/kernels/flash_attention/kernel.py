@@ -93,6 +93,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),

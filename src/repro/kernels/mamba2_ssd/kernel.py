@@ -78,6 +78,7 @@ def ssd_pallas(xdt, dA, B_, C_, *, chunk: int = 64, interpret: bool = False):
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
+        name="mamba2_ssd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, Q, hd), lambda b, h, c: (b, h, c, 0)),

@@ -82,6 +82,7 @@ def wkv6_pallas(r, k, v, logw, u, *, chunk: int = 32, interpret: bool = False):
         dimension_semantics=("parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
+        name="rwkv6_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Q, hd), lambda b, c: (b, c, 0)),

@@ -52,6 +52,7 @@ def tiled_matmul_pallas(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
+        name="tiled_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.client import Client
 from repro.configs import get_config
+from repro.core.engine.tracing import span
 from repro.launch import compile_cache
 from repro.models.common import Options
 from repro.models.model import build_model
@@ -70,15 +71,17 @@ def build(args):
 def make_batch(cfg, toks):
     """The model inputs for a (B, S) block of prompt tokens: text-only
     M-RoPE positions for the vlm family, silent frames for audio."""
-    b = {"tokens": toks}
-    if cfg.mrope:
-        B, S = toks.shape
-        b["mrope_positions"] = jnp.broadcast_to(
-            jnp.arange(S)[None, None], (3, B, S))
-    if cfg.family == "audio":
-        b["encoder_frames"] = jnp.zeros(
-            (toks.shape[0], cfg.encoder.n_frames, cfg.d_model), jnp.bfloat16)
-    return b
+    with span("serve.make_batch"):
+        b = {"tokens": toks}
+        if cfg.mrope:
+            B, S = toks.shape
+            b["mrope_positions"] = jnp.broadcast_to(
+                jnp.arange(S)[None, None], (3, B, S))
+        if cfg.family == "audio":
+            b["encoder_frames"] = jnp.zeros(
+                (toks.shape[0], cfg.encoder.n_frames, cfg.d_model),
+                jnp.bfloat16)
+        return b
 
 
 def main(argv=None, built=None):

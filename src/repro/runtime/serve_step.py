@@ -6,6 +6,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.engine.tracing import span
+
 
 def make_prefill_step(model):
     cfg = model.cfg
@@ -64,10 +66,13 @@ def greedy_generate(model, params, batch, max_new: int, cache_len: int):
     prefill = jax.jit(make_prefill_step(model))
     decode = jax.jit(make_decode_step(model))
     B, S = batch["tokens"].shape
-    tok, cache = prefill_into_cache(model, params, batch, cache_len,
-                                    prefill, decode)
+    with span("serve.prefill"):
+        tok, cache = prefill_into_cache(model, params, batch, cache_len,
+                                        prefill, decode)
     out = [tok]
-    for t in range(S, S + max_new - 1):
-        tok, cache = decode(params, tok, jnp.full((B,), t, jnp.int32), cache)
-        out.append(tok)
+    with span("serve.decode"):
+        for t in range(S, S + max_new - 1):
+            tok, cache = decode(params, tok, jnp.full((B,), t, jnp.int32),
+                                cache)
+            out.append(tok)
     return jnp.stack(out, axis=1)
