@@ -189,52 +189,71 @@ def test_program_imports_without_jax():
 
 @pytest.fixture(scope="module")
 def mesh_run(tmp_path_factory):
-    """Each mesh verb once on four virtual CPU devices, in a process of
+    """Each mesh verb twice on four virtual CPU devices, in a process of
     its own (the device count is fixed when JAX starts), with the lowered
-    modules dumped and a profiler trace around the verbs."""
+    modules dumped, the backend compiles of each round counted, and a
+    profiler trace around both rounds."""
     tmp = tmp_path_factory.mktemp("mesh")
     code = textwrap.dedent(f"""
         import json, os
         import jax, jax.numpy as jnp
         jax.config.update("jax_dump_ir_to", {str(tmp / "ir")!r})
         from repro.core.mpi_list import mesh_ops as ops
+        n = [0]
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, secs, **_: n.__setitem__(0, n[0] + (
+                event == "/jax/core/compile/backend_compile_duration")))
         mesh = jax.make_mesh((4,), ("data",))
         x = ops.scatter(mesh, jnp.arange(32, dtype=jnp.int32))
+        dest = jax.block_until_ready(x % 4)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace({str(tmp / "trace")!r},
                                  profiler_options=opts)
-        sq = ops.dfm_map(mesh, lambda v: v * v, x)
-        ops.dfm_reduce(mesh, lambda a, b: a + b, sq)
-        ops.dfm_sum(mesh, sq)
-        ops.dfm_scan(mesh, lambda a, b: a + b, x)
-        ops.group(mesh, x % 4, ops.repartition(mesh, x))
-        jax.block_until_ready(sq)
+        compiles = []
+        for _ in range(2):
+            before = n[0]
+            sq = ops.dfm_map(mesh, lambda v: v * v, x)
+            ops.dfm_reduce(mesh, lambda a, b: a + b, sq)
+            ops.dfm_sum(mesh, sq)
+            ops.dfm_scan(mesh, lambda a, b: a + b, x)
+            ops.group(mesh, dest, ops.repartition(mesh, x))
+            jax.block_until_ready(sq)
+            compiles.append(n[0] - before)
         jax.profiler.stop_trace()
-        print(json.dumps(sorted(os.listdir({str(tmp / "ir")!r}))))
+        print(json.dumps({{"dumped": sorted(os.listdir({str(tmp / "ir")!r})),
+                          "compiles": compiles}}))
     """)
     env = {**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=300, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
-    dumped = json.loads(out.stdout.strip().splitlines()[-1])
+    got = json.loads(out.stdout.strip().splitlines()[-1])
     names = defaultdict(int)
     for s in _host_spans(tmp / "trace"):
         names[s[0]] += 1
-    return dumped, names
+    return got["dumped"], names, got["compiles"]
 
 
 @pytest.mark.parametrize("verb", MESH_VERBS)
 def test_mesh_verb_names_its_program_and_span(mesh_run, verb):
-    dumped, names = mesh_run
+    dumped, names, _ = mesh_run
     assert any(f"_jit_mesh_{verb}_" in f for f in dumped), dumped
-    assert names[f"mesh.{verb}"] == 1
-    assert names[f"PjitFunction(mesh_{verb})"] >= 1
+    assert names[f"mesh.{verb}"] == 2
+    assert names[f"PjitFunction(mesh_{verb})"] >= 2
+
+
+def test_mesh_second_round_adds_no_compile(mesh_run):
+    dumped, _, compiles = mesh_run
+    assert compiles[0] >= len(MESH_VERBS) and compiles[1] == 0, compiles
+    for verb in MESH_VERBS:                  # one program a verb, kept
+        assert sum(f.endswith(f"_jit_mesh_{verb}_compile.mlir")
+                   for f in dumped) == 1, dumped
 
 
 def test_mesh_verbs_jit_no_anonymous_function(mesh_run):
-    dumped, names = mesh_run
-    assert names["mesh.repartition"] == 1
+    dumped, names, _ = mesh_run
+    assert names["mesh.repartition"] == 2
     assert not any("lambda" in f for f in dumped), dumped
     assert not any("lambda" in n for n in names if n.startswith("Pjit"))
