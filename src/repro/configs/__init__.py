@@ -11,6 +11,7 @@ from repro.configs.base import (
     RWKVConfig,
     ShapeConfig,
     SSMConfig,
+    YaRNConfig,
     applicable_shapes,
     input_specs,
 )
@@ -59,5 +60,5 @@ def get_config(name: str) -> ModelConfig:
 __all__ = [
     "ARCH_IDS", "REGISTRY", "get_config", "input_specs", "applicable_shapes",
     "SHAPES", "ShapeConfig", "ModelConfig", "RunConfig", "MLAConfig",
-    "MoEConfig", "SSMConfig", "RWKVConfig", "EncoderConfig",
+    "MoEConfig", "SSMConfig", "RWKVConfig", "EncoderConfig", "YaRNConfig",
 ]

@@ -41,6 +41,31 @@ class MoEConfig:
     dense_d_ff: int = 0           # hidden size of dense FFN (0 => d_ff)
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
+    norm_topk_prob: bool = True   # False: top-k softmax weights as they are
+    routed_scaling_factor: float = 1.0  # times unnormalised top-k weights
+    # the routed experts this chip holds (expert parallelism): experts
+    # first_expert .. first_expert + n_held - 1 of n_experts; 0 => all.
+    # The router keeps all n_experts outputs; the expert weights hold n_held
+    first_expert: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (DeepSeek-V2's `rope_scaling` of type "yarn"):
+    rotary frequencies ramped from base to base / factor between the
+    beta_fast and beta_slow correction dims, and an attention temperature."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -101,6 +126,7 @@ class ModelConfig:
     # vlm
     mrope: bool = False           # Qwen2-VL multimodal RoPE (3 position streams)
     mrope_sections: tuple = (16, 24, 24)  # per-stream rotary sections (half-dims)
+    yarn: Optional[YaRNConfig] = None   # YaRN-scaled RoPE (DeepSeek-V2)
     # hybrid (zamba2): shared attention block applied every `attn_every` ssm layers
     attn_every: int = 0
     # sub-configs
@@ -148,6 +174,7 @@ class ModelConfig:
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=8, top_k=min(self.moe.top_k, 2),
+                first_expert=0, n_held=0,
                 n_shared_experts=min(self.moe.n_shared_experts, 1),
                 d_expert=64, dense_d_ff=256, first_dense_layers=min(self.moe.first_dense_layers, 1))
         if self.ssm is not None:

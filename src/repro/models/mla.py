@@ -5,6 +5,12 @@ than full GQA KV.  Decode supports two paths:
   * naive   — decompress the whole cache to K/V each step (baseline)
   * absorb  — fold W_uk into the query and W_uv into the output so attention
               runs directly against the compressed cache (§Perf hillclimb)
+
+The rotary part of q and k is laid out as DeepSeek-V2 publishes it: its
+rope dims come in interleaved pairs (2i, 2i+1), which are de-interleaved
+(evens, then odds) before the half-split rotation.  With YaRN
+(`cfg.yarn`) the softmax scale is (dn+dr)^-0.5 times mscale_all_dim's
+term squared, in prefill and in both decode paths.
 """
 from __future__ import annotations
 
@@ -13,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.models.attention import flash_attention
 from repro.models.common import dense_init, ones_init, rms_norm, shard_hint
-from repro.models.rope import apply_rope, rope_angles
+from repro.models.rope import apply_rope, yarn_softmax_factor
 
 
 def init_mla(key, cfg, n_layers: int):
@@ -32,6 +38,23 @@ def init_mla(key, cfg, n_layers: int):
     }
 
 
+def _deinterleave(x):
+    """(..., d) with rope pairs (2i, 2i+1) -> (..., d) evens then odds."""
+    d = x.shape[-1]
+    return x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2) \
+        .reshape(x.shape)
+
+
+def _rope(x, sin, cos):
+    return apply_rope(_deinterleave(x), sin, cos)
+
+
+def softmax_scale(cfg) -> float:
+    m = cfg.mla
+    return (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5 \
+        * yarn_softmax_factor(cfg.yarn)
+
+
 def _project_q(p, x, cfg, sin, cos):
     m = cfg.mla
     H, dn, dr = cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim
@@ -39,7 +62,7 @@ def _project_q(p, x, cfg, sin, cos):
     q = shard_hint(x @ p["wq"].astype(x.dtype), "batch", None, "model_ff")
     q = q.reshape(B, S, H, dn + dr)
     qn, qr = q[..., :dn], q[..., dn:]
-    qr = apply_rope(qr, sin, cos)
+    qr = _rope(qr, sin, cos)
     return qn, qr
 
 
@@ -48,7 +71,7 @@ def _compress_kv(p, x, cfg, sin, cos):
     r, dr = m.kv_lora_rank, m.qk_rope_head_dim
     ckv_full = x @ p["wdkv"].astype(x.dtype)          # (B,S,r+dr)
     ckv = rms_norm(ckv_full[..., :r], p["kv_norm"], cfg.norm_eps)
-    krope = apply_rope(ckv_full[..., None, r:], sin, cos)[:, :, 0]  # (B,S,dr)
+    krope = _rope(ckv_full[..., None, r:], sin, cos)[:, :, 0]  # (B,S,dr)
     return ckv, krope
 
 
@@ -56,6 +79,15 @@ def mla_forward(p, x, cfg, sin, cos, *, q_block=1024, kv_block=1024,
                 skip_masked_blocks=False, return_cache=False,
                 probs_bf16=False):
     """Training / prefill: full-sequence causal MLA."""
+    with jax.named_scope("mla"):
+        return _mla_forward(p, x, cfg, sin, cos, q_block=q_block,
+                            kv_block=kv_block,
+                            skip_masked_blocks=skip_masked_blocks,
+                            return_cache=return_cache, probs_bf16=probs_bf16)
+
+
+def _mla_forward(p, x, cfg, sin, cos, *, q_block, kv_block,
+                 skip_masked_blocks, return_cache, probs_bf16):
     m = cfg.mla
     H, dn, dr, dv = cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     B, S, _ = x.shape
@@ -68,7 +100,7 @@ def mla_forward(p, x, cfg, sin, cos, *, q_block=1024, kv_block=1024,
     q = jnp.concatenate([qn, qr], axis=-1)
     k = jnp.concatenate([kn, jnp.broadcast_to(krope[:, :, None, :],
                                               (B, S, H, dr))], axis=-1)
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     ctx = flash_attention(q, k, v, causal=True, scale=scale, q_block=q_block,
                           kv_block=kv_block, skip_masked_blocks=skip_masked_blocks,
                           probs_bf16=probs_bf16)
@@ -84,6 +116,11 @@ def mla_decode(p, x, cfg, sin, cos, cache, positions, *, absorb: bool = False):
 
     Returns (out (B,1,D), new_cache).
     """
+    with jax.named_scope("mla"):
+        return _mla_decode(p, x, cfg, sin, cos, cache, positions, absorb)
+
+
+def _mla_decode(p, x, cfg, sin, cos, cache, positions, absorb):
     m = cfg.mla
     H, dn, dr, dv, r = (cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim,
                         m.v_head_dim, m.kv_lora_rank)
@@ -99,7 +136,7 @@ def mla_decode(p, x, cfg, sin, cos, cache, positions, *, absorb: bool = False):
 
     kpos = jnp.arange(T)
     allow = kpos[None, :] <= positions[:, None]           # (B,T)
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
 
     if absorb:
         wuk = p["wuk"].astype(x.dtype).reshape(r, H, dn)

@@ -6,6 +6,9 @@
     logits, cache, aux = model.forward(params, batch, mode="prefill")
     logits, cache = model.decode_step(params, tokens, pos, cache)
     cache = model.init_cache(batch, max_len, abstract=True)
+
+An MoE model's serving steps also return their MoE counts, summed over
+layers, where asked (`moe_counts=True`; `moe.N_COUNTS`).
 """
 from __future__ import annotations
 
@@ -32,8 +35,9 @@ class Model:
         key = key if key is not None else jax.random.PRNGKey(0)
         return jax.eval_shape(lambda k: self._mod.init_lm(k, self.cfg), key)
 
-    def forward(self, params, batch: dict, mode: str = "train"):
-        kw = {}
+    def forward(self, params, batch: dict, mode: str = "train",
+                moe_counts: bool = False):
+        kw = {"moe_counts": True} if moe_counts else {}
         if self.cfg.mrope and "mrope_positions" in batch:
             kw["mrope_positions"] = batch["mrope_positions"]
         if self.cfg.family == "audio":
@@ -41,9 +45,11 @@ class Model:
         return self._mod.forward(params, self.cfg, batch["tokens"],
                                  opts=self.opts, mode=mode, **kw)
 
-    def decode_step(self, params, tokens, positions, cache):
+    def decode_step(self, params, tokens, positions, cache,
+                    moe_counts: bool = False):
+        kw = {"moe_counts": True} if moe_counts else {}
         return self._mod.decode_step(params, self.cfg, tokens, positions,
-                                     cache, opts=self.opts)
+                                     cache, opts=self.opts, **kw)
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16,
                    abstract: bool = False):

@@ -86,13 +86,18 @@ def _attn_scale(cfg) -> float:
 
 
 def apply_block(bp, x, cfg, sin, cos, *, opts: Options, window=None,
-                mode: str = "train", cache=None, positions=None):
+                mode: str = "train", cache=None, positions=None,
+                experts=None, layer=None):
     """One transformer block.
 
-    mode: train | prefill | decode.
+    mode: train | prefill | decode.  Serving (prefill, decode) runs an
+    MoE layer dropless (`moe.apply_moe_dropless`), training with capacity;
+    a scanned MoE layer's expert weights come as the whole stack
+    (`experts`) and its index (`layer`), for the kernel to read in place.
     cache: (k, v) (B,T,Hkv,hd) or MLA (ckv, krope) — required for decode.
-    Returns (x, cache_out, aux) where cache_out is the new/filled cache
-    entry (prefill/decode) or None (train).
+    Returns (x, cache_out, aux, counts) where cache_out is the new/filled
+    cache entry (prefill/decode) or None (train), and counts the serving
+    MoE layer's `moe.N_COUNTS` counts (None for any other layer).
     """
     aux = jnp.zeros((), jnp.float32)
     h = _norm(x, bp["ln1"], cfg)
@@ -148,15 +153,19 @@ def apply_block(bp, x, cfg, sin, cos, *, opts: Options, window=None,
     x = x + a_out
 
     h = _norm(x, bp["ln2"], cfg)
-    if "router" in bp["mlp"]:
+    counts = None
+    if "router" not in bp["mlp"]:
+        f_out = apply_ffn(bp["mlp"], h, cfg)
+    elif mode == "train":
         f_out, aux = moe_mod.apply_moe(bp["mlp"], h, cfg,
                                        group_size=opts.moe_group)
     else:
-        f_out = apply_ffn(bp["mlp"], h, cfg)
+        f_out, counts = moe_mod.apply_moe_dropless(bp["mlp"], h, cfg,
+                                                   experts, layer)
     if cfg.post_norms:
         f_out = _norm(f_out, bp["pn2"], cfg)
     x = x + f_out
-    return x, cache_out, aux
+    return x, cache_out, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -220,12 +229,38 @@ def _angles(cfg, positions, mrope_positions):
     hd = cfg.mla.qk_rope_head_dim if cfg.mla is not None else cfg.resolved_head_dim
     if cfg.mrope and mrope_positions is not None:
         return mrope_angles(mrope_positions, cfg.mrope_sections, hd, cfg.rope_theta)
-    return rope_angles(positions, hd, cfg.rope_theta)
+    return rope_angles(positions, hd, cfg.rope_theta, cfg.yarn)
+
+
+def _add_counts(total, counts):
+    return total if counts is None else total + counts
+
+
+def _scan_inputs(params, mode):
+    """The scan's per-layer inputs, and the stacked expert weights a
+    serving MoE layer reads in place (None otherwise): slicing a layer's
+    experts out of the stack would copy them every layer and step."""
+    blocks = params["blocks"]
+    if mode == "train" or "router" not in blocks["mlp"]:
+        return {"bp": blocks}, None
+    mlp = dict(blocks["mlp"])
+    experts = [mlp.pop(k) for k in moe_mod.EXPERT_WEIGHTS]
+    n = experts[0].shape[0]
+    return {"bp": dict(blocks, mlp=mlp), "layer": jnp.arange(n)}, experts
+
+
+def _no_counts(cfg):
+    """The zero of the serving MoE counts, or None for a model without an
+    MoE layer (which then carries nothing)."""
+    if cfg.moe is None:
+        return None
+    return jnp.zeros((moe_mod.N_COUNTS,), jnp.int32)
 
 
 def forward(params, cfg, tokens, *, opts: Options = None, mode: str = "train",
-            mrope_positions=None, dtype=jnp.bfloat16):
-    """tokens (B,S) -> logits (B,S,Vp) [, cache] ; plus moe aux loss."""
+            mrope_positions=None, dtype=jnp.bfloat16, moe_counts: bool = False):
+    """tokens (B,S) -> logits (B,S,Vp) [, cache] ; plus moe aux loss; plus,
+    with `moe_counts` in prefill, the MoE counts summed over layers."""
     opts = opts or Options()
     B, S = tokens.shape
     x = _embed(params, cfg, tokens, dtype)
@@ -233,33 +268,39 @@ def forward(params, cfg, tokens, *, opts: Options = None, mode: str = "train",
     sin, cos = _angles(cfg, positions, mrope_positions)
     windows = _layer_windows(cfg, cfg.n_layers - _n_first(cfg), S)
     aux_total = jnp.zeros((), jnp.float32)
+    counts = _no_counts(cfg) if mode != "train" else None
 
     first_caches = []
     for fb in params.get("first", ()):
-        x, c_out, aux_l = apply_block(fb, x, cfg, sin, cos, opts=opts,
-                                      window=None, mode=mode)
+        x, c_out, aux_l, cnt = apply_block(fb, x, cfg, sin, cos, opts=opts,
+                                           window=None, mode=mode)
         first_caches.append(c_out)
         aux_total = aux_total + aux_l
+        counts = _add_counts(counts, cnt)
+
+    xs, experts = _scan_inputs(params, mode)
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, counts = carry
         bp = xs["bp"]
         w = xs.get("w")
-        x, cache_out, aux_l = apply_block(bp, x, cfg, sin, cos, opts=opts,
-                                          window=w, mode=mode)
-        return (x, aux + aux_l), cache_out
+        x, cache_out, aux_l, cnt = apply_block(
+            bp, x, cfg, sin, cos, opts=opts, window=w, mode=mode,
+            experts=experts, layer=xs.get("layer"))
+        return (x, aux + aux_l, _add_counts(counts, cnt)), cache_out
 
-    xs = {"bp": params["blocks"]}
     if windows is not None:
         xs["w"] = windows
-    (x, aux_total), caches = jax.lax.scan(
-        maybe_remat(body, opts.remat), (x, aux_total), xs)
+    (x, aux_total, counts), caches = jax.lax.scan(
+        maybe_remat(body, opts.remat), (x, aux_total, counts), xs)
 
     if mode == "prefill":
         # serving only needs next-token logits after prefill
         x_last = _norm(x[:, -1:], params["final_norm"], cfg)
         logits = _head(params, cfg, x_last)[:, 0]
-        return logits, {"layers": caches, "first": tuple(first_caches)}, aux_total
+        out = (logits, {"layers": caches, "first": tuple(first_caches)},
+               aux_total)
+        return out + (counts,) if moe_counts else out
     x = _norm(x, params["final_norm"], cfg)
     logits = _head(params, cfg, x)
     return logits, aux_total
@@ -289,9 +330,9 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, abstract=False
 
 
 def decode_step(params, cfg, tokens, positions, cache, *, opts: Options = None,
-                dtype=jnp.bfloat16):
+                dtype=jnp.bfloat16, moe_counts: bool = False):
     """One token per sequence. tokens/positions (B,). Returns (logits (B,Vp),
-    new_cache, aux)."""
+    new_cache), and with `moe_counts` the MoE counts summed over layers."""
     opts = opts or Options()
     B = tokens.shape[0]
     x = _embed(params, cfg, tokens[:, None], dtype)
@@ -304,26 +345,34 @@ def decode_step(params, cfg, tokens, positions, cache, *, opts: Options = None,
     S_max = jax.tree_util.tree_leaves(cache["layers"])[0].shape[2]
     windows = _layer_windows(cfg, cfg.n_layers - _n_first(cfg), S_max)
 
+    counts = _no_counts(cfg)
     new_first = []
     for fb, fc in zip(params.get("first", ()), cache["first"]):
-        x, c_out, _ = apply_block(fb, x, cfg, sin, cos, opts=opts, window=None,
-                                  mode="decode", cache=fc, positions=positions)
+        x, c_out, _, cnt = apply_block(fb, x, cfg, sin, cos, opts=opts,
+                                       window=None, mode="decode", cache=fc,
+                                       positions=positions)
         new_first.append(c_out)
+        counts = _add_counts(counts, cnt)
 
-    def body(x, xs):
+    xs, experts = _scan_inputs(params, "decode")
+    xs["cache"] = cache["layers"]
+
+    def body(carry, xs):
+        x, counts = carry
         bp = xs["bp"]
         w = xs.get("w")
         cache_l = xs["cache"]
-        x, c_out, _ = apply_block(bp, x, cfg, sin, cos, opts=opts, window=w,
-                                  mode="decode", cache=cache_l,
-                                  positions=positions)
-        return x, c_out
+        x, c_out, _, cnt = apply_block(bp, x, cfg, sin, cos, opts=opts,
+                                       window=w, mode="decode", cache=cache_l,
+                                       positions=positions, experts=experts,
+                                       layer=xs.get("layer"))
+        return (x, _add_counts(counts, cnt)), c_out
 
-    xs = {"bp": params["blocks"], "cache": cache["layers"]}
     if windows is not None:
         xs["w"] = windows
-    x, new_layers = jax.lax.scan(body, x, xs)
+    (x, counts), new_layers = jax.lax.scan(body, (x, counts), xs)
 
     x = _norm(x, params["final_norm"], cfg)
     logits = _head(params, cfg, x)[:, 0]
-    return logits, {"layers": new_layers, "first": tuple(new_first)}
+    out = (logits, {"layers": new_layers, "first": tuple(new_first)})
+    return out + (counts,) if moe_counts else out

@@ -8,6 +8,8 @@ import pytest
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.mamba2_ssd.ops import ssd, ssd_ref, ssd_sequential_ref
+from repro.kernels.moe_gmm.kernel import visit_plan
+from repro.kernels.moe_gmm.ops import moe_gmm, moe_gmm_ref
 from repro.kernels.rwkv6_scan.ops import (wkv6, wkv6_ref,
                                           wkv6_sequential_ref)
 from repro.kernels.tiled_matmul.ops import tiled_matmul
@@ -102,3 +104,50 @@ def test_flash_kernel_matches_model_blockwise():
                            interpret=True)
     assert float(jnp.max(jnp.abs(blockwise.transpose(0, 2, 1, 3)
                                  - kern))) < 2e-5
+
+
+def _experts(E, D, F, dtype, key=KEY):
+    ks = jax.random.split(key, 3)
+    w1 = jax.random.normal(ks[0], (E, D, F)) / D ** 0.5
+    w3 = jax.random.normal(ks[1], (E, D, F)) / D ** 0.5
+    w2 = jax.random.normal(ks[2], (E, F, D)) / F ** 0.5
+    return w1.astype(dtype), w3.astype(dtype), w2.astype(dtype)
+
+
+@pytest.mark.parametrize("sizes,tm", [
+    ([5, 0, 17, 9], None),                  # ragged, an empty expert
+    ([0, 0, 1, 0], None),                   # one row
+    ([30, 0, 100, 0, 1, 60, 9, 0], 32),     # groups across tiles
+    ([64, 64, 0, 0], 32),                   # groups on tile boundaries
+    ([0, 0, 0, 0], None),                   # no row: nothing computed
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm(sizes, tm, dtype):
+    """Rows sorted by expert through their experts' gated MLPs; rows past
+    the groups' total (5 more here) come back as zeros."""
+    D, F, E = 128, 256, len(sizes)
+    N = sum(sizes) + 5
+    x = jax.random.normal(jax.random.PRNGKey(1), (N, D)).astype(dtype)
+    w1, w3, w2 = _experts(E, D, F, dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    out = moe_gmm(x, w1, w3, w2, gs, tm=tm, bf=128, interpret=True)
+    ref = moe_gmm_ref(x, w1, w3, w2, gs)
+    tol = 1e-4 if dtype == jnp.float32 else 0.05
+    assert out.shape == (N, D) and out.dtype == dtype
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                 - ref.astype(jnp.float32)))) < tol
+    assert not bool(jnp.any(out[sum(sizes):]))
+
+
+def test_moe_gmm_visits_only_experts_with_rows():
+    """The grid's visits name only experts with rows, each tile an
+    expert's rows touch once, in order; the rest repeat the last."""
+    gs = jnp.asarray([3, 0, 20, 0, 9], jnp.int32)      # tm 8: tiles 0-4
+    expert, tile, starts, ends, nv = visit_plan(gs, n_tiles=5, tm=8)
+    n = int(nv[0])
+    got = list(zip(expert[:n].tolist(), tile[:n].tolist()))
+    assert got == [(0, 0), (2, 0), (2, 1), (2, 2), (4, 2), (4, 3)]
+    assert expert[n:].tolist() == [4] * (len(expert) - n)
+    assert tile[n:].tolist() == [3] * (len(tile) - n)
+    assert starts.tolist() == [0, 3, 3, 23, 23]
+    assert ends.tolist() == [3, 3, 23, 23, 32]

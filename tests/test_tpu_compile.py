@@ -1,4 +1,4 @@
-"""The four Pallas kernels compile for a TPU v5e at real widths.
+"""The five Pallas kernels compile for a TPU v5e at real widths.
 
 The chip is described, not attached (`jax.experimental.topologies`): the
 TPU compiler that ships with libtpu refuses here what the chip would
@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.mamba2_ssd.kernel import ssd_pallas
+from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.kernels.rwkv6_scan.kernel import wkv6_pallas
 from repro.kernels.tiled_matmul.kernel import tiled_matmul_pallas
 
@@ -86,3 +87,14 @@ def test_mamba2_ssd_compiles(one_chip, no_persistent_cache):
     _compile(lambda x, a, b, c: ssd_pallas(x, a, b, c, chunk=64), one_chip,
              ((1, 2048, 80, 64), f32), ((1, 2048, 80), f32),
              ((1, 2048, 1, 64), f32), ((1, 2048, 1, 64), f32))
+
+
+@pytest.mark.parametrize("rows", [6, 12288])
+def test_moe_gmm_compiles(one_chip, no_persistent_cache, rows):
+    """deepseek-v2-lite's experts held on one chip: 16 of D 2048, F 1408,
+    for a decode step's 6 routed rows and a 2048-token prefill's 12,288."""
+    bf16 = jnp.bfloat16
+    _compile(lambda x, a, b, c, g: moe_gmm(x, a, b, c, g), one_chip,
+             ((rows, 2048), bf16), ((16, 2048, 1408), bf16),
+             ((16, 2048, 1408), bf16), ((16, 1408, 2048), bf16),
+             ((16,), jnp.int32))
