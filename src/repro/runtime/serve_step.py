@@ -8,7 +8,19 @@ experts with a row, MoE layer calls; summed over layers).
 `greedy_generate` sums them on the device over the batch's steps, fetches
 them with the tokens, and records each batch's with its completion time:
 `moe_counts()` lists the records.  A model without MoE layers builds and
-runs its steps as before and records nothing."""
+runs its steps as before and records nothing.
+
+`greedy_generate` builds a model's jitted prefill and decode steps on its
+first call and keeps them on the model instance (its `_serve_steps`), so
+they are freed with the model and no two models share one.  A later call
+reuses them through JAX's dispatch fast path: nothing is traced, lowered
+or loaded from the compile cache for a batch shape the model has run
+before.  `make_prefill_step` and `make_decode_step` are looked up in this
+module when the steps are built, and the weights and the batch stay
+arguments, never constants of the kept programs.  `jit_cache_info()`
+counts the calls that built the steps or ran a new batch shape (misses,
+where JAX traces again) and the rest (hits); `jit_cache_clear()` drops
+every model's kept steps and resets the counts."""
 from __future__ import annotations
 
 import threading
@@ -53,6 +65,54 @@ def moe_counts() -> list[dict]:
 
 def moe_counts_clear() -> None:
     _COUNTS.clear()
+
+
+class _StepCache:
+    """Each model's kept jitted steps and the batch shapes they have run,
+    with the hits and misses since the last `clear()`.  The steps are kept
+    under the model's `id` and the generation, which `clear()` moves on:
+    a copy of a model, or its steps from before a `clear()`, is rebuilt."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.generation = 0
+        self.clear()
+
+    def clear(self) -> None:
+        with self.lock:
+            self.generation += 1
+            self.hits = self.misses = 0
+
+    def get(self, model, shape: tuple):
+        """The model's (prefill, decode), built on its first call."""
+        with self.lock:
+            key = (id(model), self.generation)
+            kept = getattr(model, "_serve_steps", None)
+            if kept is None or kept["key"] != key:
+                kw = {"moe_counts": True} if model.cfg.moe is not None else {}
+                kept = model._serve_steps = {
+                    "key": key, "shapes": set(),
+                    "prefill": jax.jit(make_prefill_step(model, **kw)),
+                    "decode": jax.jit(make_decode_step(model, **kw))}
+            if shape in kept["shapes"]:
+                self.hits += 1
+            else:
+                self.misses += 1
+                kept["shapes"].add(shape)
+            return kept["prefill"], kept["decode"]
+
+
+_STEPS = _StepCache()
+
+
+def jit_cache_info() -> dict:
+    """Hits and misses of the kept serving steps, all models together."""
+    with _STEPS.lock:
+        return {"hits": _STEPS.hits, "misses": _STEPS.misses}
+
+
+def jit_cache_clear() -> None:
+    _STEPS.clear()
 
 
 def make_prefill_step(model, moe_counts: bool = False):
@@ -111,14 +171,11 @@ def prefill_into_cache(model, params, batch, cache_len: int, prefill,
 
 
 def greedy_generate(model, params, batch, max_new: int, cache_len: int):
-    """Prefill, then greedy-decode max_new tokens.
-    An MoE model's tokens come back as a host array, fetched together with
-    the batch's MoE counts (recorded for `moe_counts()`)."""
-    moe = model.cfg.moe is not None
-    kw = {"moe_counts": True} if moe else {}
-    prefill = jax.jit(make_prefill_step(model, **kw))
-    decode = jax.jit(make_decode_step(model, **kw))
+    """Prefill, then greedy-decode max_new tokens with the model's kept
+    steps.  An MoE model's tokens come back as a host array, fetched
+    together with the batch's MoE counts (recorded for `moe_counts()`)."""
     B, S = batch["tokens"].shape
+    prefill, decode = _STEPS.get(model, (B, S, cache_len))
     with span("serve.prefill"):
         tok, cache, *counts = prefill_into_cache(model, params, batch,
                                                  cache_len, prefill, decode)
@@ -130,7 +187,7 @@ def greedy_generate(model, params, batch, max_new: int, cache_len: int):
             out.append(tok)
             counts += step_counts
     tokens = jnp.stack(out, axis=1)
-    if not moe:
+    if model.cfg.moe is None:
         return tokens
     tokens, total = jax.device_get((tokens, jnp.sum(jnp.stack(counts), 0)))
     _COUNTS.add(total)
