@@ -117,9 +117,8 @@ def _run_mode(mode: str) -> dict:
                           prods[(j * 7 + 3) % N_PRODUCERS], key=f"xc{j}")
                  for j in range(N_CONSUMERS)]
         values = c.gather(sinks, timeout=120)
-        with c.engine._xfer_lock:
-            by_path = {p: list(v) for p, v in c.engine.xfer_totals.items()}
-        lost = c.engine.xfer_lost_total
+        snap = c.engine.data_plane.snapshot()
+        by_path, lost = snap["by_path"], snap["lost"]
     wall = time.perf_counter() - t0
     if values != _expected():
         raise AssertionError(f"[{mode}] sink digests corrupted — the data "

@@ -12,16 +12,22 @@ makes that claim *measurable* in one place instead of three ad-hoc loops:
                  forwarding-tree TreeBackend) speaking the Table 2 verbs
                  incl. the batched CompleteSteal; every call timed as an
                  `rpc` event (tree hops as `op="hop:L<k>"`)
-    executor.py  the worker pool: inproc / thread / tree / proc
-                 transports, CompleteSteal piggybacking (complete+steal
-                 in one RTT), Steal-n batching, sharded routing,
-                 heap-scheduled slots/priority launch (pmake EFT)
+    executor.py  the Engine and its ONE dispatch loop (`Engine.run`):
+                 the shared steps of every round written once (ingest,
+                 membership, a worker's death, retry policy, terminal
+                 bookkeeping `_finish`, termination and stall test)
+    rounds.py    the transport step, one call a round: `InlineRounds`
+                 (inproc / thread / tree: CompleteSteal piggybacking,
+                 Steal-n batching, heap-scheduled slots/priority launch
+                 for pmake EFT) and `ProcRounds` (proc: drain what the
+                 ProcBackend queued, supervise worker processes)
     comm/        the transport registry: Connector/Listener pairs per
                  address scheme, TransportFamily per `transport=` name;
                  the proc family spawns worker PROCESSES speaking
                  Table-2 frames over TCP (Hello handshake, heartbeat
                  leases, cloudpickle at the boundary, multi-host join
-                 via `python -m repro.core.engine.comm.worker`)
+                 via `python -m repro.core.engine.comm.worker`); its
+                 `DataPlane` fetches, attributes and recomputes values
     faults.py    heartbeat leases, dead-worker requeue, seeded fault and
                  straggler injection (no wall-clock dependence in tests)
     journal.py   write-ahead journal + compacted checkpoints for the

@@ -261,18 +261,22 @@ def _make_tree(*, workers=1, tree_fanout=4, tree_levels=1, shards=1,
                        tracer=tracer)
 
 
-def _make_proc(*, shards=1, lease_timeout=None, clock=None, tracer=None,
-               steal_n=1, resident=False, proc_host="127.0.0.1",
-               proc_port=0, heartbeat_s=0.5, inline_bytes=65536,
-               spill_bytes=64 * 1024 * 1024, **_):
+def _make_proc(*, inner=None, shards=1, lease_timeout=None, clock=None,
+               tracer=None, steal_n=1, resident=False,
+               proc_host="127.0.0.1", proc_port=0, heartbeat_s=0.5,
+               inline_bytes=65536, spill_bytes=64 * 1024 * 1024, **_):
+    """A ProcBackend over a fresh local backend, or over a caller's
+    `inner` backend, which then stays the caller's to close."""
     from repro.core.engine.comm.proc import ProcBackend
 
-    inner = _make_local(shards=shards, lease_timeout=lease_timeout,
-                        clock=clock, tracer=tracer)
+    owns_inner = inner is None
+    if owns_inner:
+        inner = _make_local(shards=shards, lease_timeout=lease_timeout,
+                            clock=clock, tracer=tracer)
     return ProcBackend(inner, host=proc_host, port=proc_port,
                        steal_n=steal_n, resident=resident,
-                       heartbeat_s=heartbeat_s, inline_bytes=inline_bytes,
-                       spill_bytes=spill_bytes)
+                       heartbeat_s=heartbeat_s, owns_inner=owns_inner,
+                       inline_bytes=inline_bytes, spill_bytes=spill_bytes)
 
 
 register_transport(TransportFamily(

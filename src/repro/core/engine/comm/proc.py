@@ -18,8 +18,10 @@ and the plain Table-2 verbs:
     value-payload, "e": error, "d": duration, "n": nbytes, "x": xfer
     stats, "as": store-as alias}]` — and are stripped to `(name, ok)`
     before reaching the TaskServer (which stays unchanged); the
-    payloads/durations/xfer stats are queued as completion records for
-    the engine's supervision loop (`Engine._run_proc`) to drain.
+    payloads/durations/xfer stats are queued as completion records,
+    which the engine's dispatch loop drains once a round through
+    `ProcBackend.results` (values decoded or handed out as lazy
+    `RemoteValue`s, run spans stamped from the reported durations).
   * The data plane lives here too: a result above `inline_bytes` stays
     in its producing worker's local store — the entry carries "n"
     (byte count) instead of "v", and the door records the LOCATION
@@ -29,7 +31,10 @@ and the plain Table-2 verbs:
     A `__xfer_lost__:`-prefixed failure (a dependency value neither its
     producer nor the hub could serve — the producer was SIGKILLed
     before replicating) is WITHHELD from the scheduler (the task stays
-    leased) and queued on `lost` for the engine to recompute.
+    leased) and queued on `lost` for the engine to recompute.  What the
+    engine process does with those values — fetch, transfer
+    attribution, recompute — is the `DataPlane` (`comm/data_plane.py`)
+    each `ProcBackend` owns.
   * Hello / Heartbeat / Fetch / Spill are answered here (join
     registration, liveness touch, dependency-value serving) and never
     forwarded.
@@ -59,8 +64,10 @@ from repro.core.dwork.api import (XFER_LOST_PREFIX, CompleteSteal, ExitResp,
                                   Fetch, Heartbeat, Hello, HelloResp, LocMsg,
                                   NotFound, Spill, TaskMsg, ValueMsg)
 from repro.core.engine.comm import core as comm_core
-from repro.core.engine.comm.serialize import dumps
-from repro.core.engine.model import RPC, STOLEN
+from repro.core.engine.comm.data_plane import DataPlane
+from repro.core.engine.comm.serialize import RemoteValue, dumps, loads
+from repro.core.engine.model import (RPC, RUN_END, RUN_START, STOLEN,
+                                     TaskResult)
 
 
 class _FrontDoor:
@@ -86,7 +93,7 @@ class _FrontDoor:
         self.joined: deque = deque()     # workers whose Hello arrived
         self.exited: set = set()         # workers told to exit (clean)
         self.stolen_at: dict = {}        # task -> STOLEN timestamp
-        self.requeued = 0                # lease requeues seen at the wire
+        self.requeued: deque = deque()   # lease requeue counts at the wire
         self.stopping = False            # resident drain: let DONE through
         self._next_rid = 0               # auto ids for anonymous joins
 
@@ -211,10 +218,10 @@ class _FrontDoor:
             # requeued its leases (checked under the same lock, so the
             # order is decided, not raced)
             gone = w in self.exited
-            before = b.requeued_delta()
+            before = b._requeued_total()
             resp = b.wire_handle(CompleteSteal(worker=w, done=done,
                                                n=0 if gone else msg.n))
-            rq = b.requeued_delta() - before
+            rq = b._requeued_total() - before
         if sampled:
             dt = time.perf_counter() - t0
             tracer.emit(RPC, op="proc:complete_steal", dt=dt)
@@ -223,9 +230,9 @@ class _FrontDoor:
                 m.observe("proc:complete_steal", dt)
         if recs or rq:
             with self.lock:
-                if recs:
-                    self.records.extend(recs)
-                self.requeued += rq
+                self.records.extend(recs)
+                if rq:
+                    self.requeued.append(rq)
         if gone:
             return ExitResp()      # no polling conversion: die, worker
         if isinstance(resp, TaskMsg):
@@ -253,8 +260,9 @@ class ProcBackend:
     the execute callback — failing fast on an unpicklable one),
     `start_pool`/`spawn`/`kill_worker`/`stop_pool` (local process
     lifecycle, atexit-reaped so no orphans survive the interpreter), and
-    the supervision taps `drain_records` / `drain_joined` /
-    `drain_requeued` / `check_dead` / `all_done`."""
+    the supervision taps `drain_records` / `results` / `drain_joined` /
+    `drain_requeued` / `drain_lost` / `drain_failed` / `check_dead` /
+    `all_done`.  Its `data` is the engine side of the data plane."""
 
     def __init__(self, inner, *, host: str = "127.0.0.1", port: int = 0,
                  steal_n: int = 1, resident: bool = False,
@@ -286,15 +294,13 @@ class ProcBackend:
         self.door = _FrontDoor(self)
         self.listener = comm_core.listen(f"tcp://{host}:{port}", self.door)
         self.procs: dict = {}            # worker -> subprocess.Popen
+        self.data = DataPlane(self)
         self._closed = False
         atexit.register(self._kill_all)  # orphan reaping on interpreter exit
 
     # ------------------------------------------------------------ wire
     def wire_handle(self, msg):
         return self._wire(msg)
-
-    def requeued_delta(self) -> int:
-        return self.inner._requeued_total()
 
     @property
     def address(self) -> str:
@@ -320,6 +326,7 @@ class ProcBackend:
     def spawn(self, worker: str) -> subprocess.Popen:
         import repro
 
+        self.door.exited.discard(worker)   # a rejoin under an old id
         env = dict(os.environ)
         src = str(os.path.dirname(os.path.dirname(
             os.path.abspath(repro.__file__))))
@@ -382,9 +389,6 @@ class ProcBackend:
                     pass
 
     # -------------------------------------------------- supervision taps
-    def connected(self) -> set:
-        return set(self.door.pids)
-
     def worker_pids(self) -> dict:
         """worker -> OS pid, for every process that completed Hello."""
         return dict(self.door.pids)
@@ -392,57 +396,69 @@ class ProcBackend:
     def has_records(self) -> bool:
         return bool(self.door.records)
 
-    def drain_records(self) -> list:
-        d = self.door
-        if not d.records:
+    def _take(self, queue: deque) -> list:
+        if not queue:
             return []
-        with d.lock:
-            out = list(d.records)
-            d.records.clear()
+        with self.door.lock:
+            out = list(queue)
+            queue.clear()
         return out
+
+    def drain_records(self) -> list:
+        return self._take(self.door.records)
+
+    def results(self, recs: list, done):
+        """TaskResults from drained completion records, lazily (so each
+        `done(name)` dedupe sees the bookkeeping of the records before
+        it): transfers attributed, duplicates after a requeue dropped,
+        values decoded or left remote, run spans stamped."""
+        tracer, data, stolen_at = self.tracer, self.data, self.door.stolen_at
+        for w, name, ok, err, dur, payload, nbytes, xfers in recs:
+            for path, n, dt in xfers or ():
+                data.record(name, w, path, n, dt)
+            if done(name):
+                stolen_at.pop(name, None)
+                continue
+            value = None
+            if ok and payload is not None:
+                try:
+                    value = loads(payload)
+                except Exception as e:  # noqa: BLE001
+                    ok = False
+                    err = f"result deserialization failed: {e!r}"
+            elif ok and nbytes:   # stayed in the producer's store
+                value = RemoteValue(name, nbytes, data.fetch)
+            # the run span from the worker's reported duration, clamped
+            # to the STOLEN stamp so report pairing never sees negative
+            # dispatch
+            t1 = tracer.clock()
+            t0 = t1 - dur
+            t_stolen = stolen_at.pop(name, None)
+            if t_stolen is not None and t0 < t_stolen:
+                t0 = t_stolen
+            tracer.emit_at(t0, RUN_START, task=name, worker=w)
+            tracer.emit_at(t1, RUN_END, task=name, worker=w)
+            yield TaskResult(task=name, ok=ok, worker=w, t_start=t0,
+                             t_end=t1, value=value, error=err)
 
     def drain_joined(self) -> list:
-        d = self.door
-        if not d.joined:
-            return []
-        with d.lock:
-            out = list(d.joined)
-            d.joined.clear()
-        return out
+        return self._take(self.door.joined)
 
     def drain_requeued(self) -> int:
-        d = self.door
-        if not d.requeued:
-            return 0
-        with d.lock:
-            n = d.requeued
-            d.requeued = 0
-        return n
+        return sum(self._take(self.door.requeued))
 
     def drain_lost(self) -> list:
         """-> [(worker, task, missing-dep), ...]: withheld completions
         whose dependency value is unrecoverable (the engine recomputes
         the missing value, then Transfer-requeues the dependent)."""
-        d = self.door
-        if not d.lost:
-            return []
-        with d.lock:
-            out = list(d.lost)
-            d.lost.clear()
-        return out
+        return self._take(self.door.lost)
 
     def drain_failed(self) -> list:
         """-> [(worker, task, err), ...]: failed completions the door
         withheld because `retry_check` approved them (the task is still
         leased to the worker) — the engine applies the policy's backoff
         and Transfer-requeues, or fails them for real."""
-        d = self.door
-        if not d.failed_held:
-            return []
-        with d.lock:
-            out = list(d.failed_held)
-            d.failed_held.clear()
-        return out
+        return self._take(self.door.failed_held)
 
     def check_dead(self, grace: float) -> list:
         """-> [(worker, reason)]: locally-spawned processes that exited
@@ -494,6 +510,7 @@ class ProcBackend:
         return self.inner.steal(worker, n)
 
     def complete(self, worker, name, ok=True):
+        self.door.stolen_at.pop(name, None)
         return self.inner.complete(worker, name, ok=ok)
 
     def complete_steal(self, worker, done, n=0):

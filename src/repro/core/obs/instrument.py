@@ -95,7 +95,7 @@ class RpcMetrics:
 class XferMetrics:
     """Data-plane transfer metrics (transport="proc"): per-path latency
     histograms plus byte/count totals, fed by the engine's unsampled
-    xfer attribution (`Engine._record_xfer`).  Bound observers are
+    xfer attribution (`DataPlane.record`).  Bound observers are
     cached per path — there are only two ("peer"/"hub"), so the hot
     call is one dict hit."""
 

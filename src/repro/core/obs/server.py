@@ -210,16 +210,13 @@ class StatsServer:
                           "dropped": tracer.dropped,
                           "rpc_seen": tracer.rpc_seen},
             }
-            totals = getattr(eng, "xfer_totals", None)
-            if totals is not None:
-                with eng._xfer_lock:
-                    snap = {p: list(v) for p, v in totals.items()}
-                payload["engine"]["xfer"] = {
-                    "lost": eng.xfer_lost_total,
-                    "by_path": {p: {"n": n, "bytes": b,
-                                    "total_s": round(t, 6)}
-                                for p, (n, b, t) in sorted(snap.items())},
-                }
+            xfer = eng.data_plane.snapshot()
+            payload["engine"]["xfer"] = {
+                "lost": xfer["lost"],
+                "by_path": {p: {"n": n, "bytes": b, "total_s": round(t, 6)}
+                            for p, (n, b, t)
+                            in sorted(xfer["by_path"].items())},
+            }
             payload["rates"] = {
                 "tasks_per_s": (round(rate, 3)
                                 if rate is not None else None),

@@ -6,9 +6,10 @@ measured on the running code)."""
 import pytest
 
 from repro.core.dwork import Client, InProcTransport, TaskServer, run_pool
-from repro.core.engine import (COMPLETED, CREATED, READY, RUN_END, RUN_START,
-                               STOLEN, Engine, FaultPlan, ManualClock,
-                               TraceRecorder, crosscheck)
+from repro.core.engine import (COMPLETED, CREATED, FAILED, READY, RUN_END,
+                               RUN_START, STOLEN, Engine, FaultPlan,
+                               ManualClock, RetryPolicy, TraceRecorder,
+                               crosscheck)
 from repro.core.metg import METGModel, PAPER_DWORK_RTT
 from repro.core.mpi_list import Context
 from repro.core.pmake import PMake
@@ -519,3 +520,71 @@ def test_trace_counts_conserved(thousand_task_results):
     assert tr.count(STOLEN) >= 1000
     done_tasks = {e.task for e in tr.of(COMPLETED)}
     assert len(done_tasks) == 1000
+
+
+# ------------------------------------------------- one loop, four paths
+
+def _contract_dag(marker: str) -> list:
+    """-> [(name, fn, deps)]: a diamond, a failure poisoning two
+    successors, and a task that fails once (the marker file makes the
+    first run fail in any process) and succeeds on its retry."""
+    import os
+
+    def flaky():
+        if not os.path.exists(marker):
+            open(marker, "w").close()
+            raise RuntimeError("transient")
+        return 5
+
+    def bad():
+        raise ValueError("boom")
+
+    return [("a", lambda: 1, ()), ("b", lambda: 2, ("a",)),
+            ("c", lambda: 3, ("a",)), ("d", lambda: 4, ("b", "c")),
+            ("bad", bad, ()), ("bad1", lambda: 6, ("bad",)),
+            ("bad2", lambda: 7, ("bad1",)), ("flaky", flaky, ())]
+
+
+@pytest.mark.parametrize("path", ["inproc-fast", "inproc-heap", "thread",
+                                  "proc"])
+def test_terminal_contract_on_every_path(path, tmp_path):
+    """The one dispatch loop keeps the same terminal contract on every
+    transport path: one COMPLETED or FAILED event and one on_result per
+    task, worker stats that count every executed completion, exec_failed
+    equal to the failures, and a complete result table."""
+    seen = []
+    kw = dict(workers=2, on_result=lambda name, *_: seen.append(name),
+              retry=RetryPolicy(max_attempts=3, retry_on=("transient",)))
+    if path == "inproc-fast":
+        kw["resident"] = True
+    elif path != "inproc-heap":
+        kw["transport"] = path
+    if path == "proc":
+        kw["heartbeat_s"] = 0.1
+    eng = Engine(**kw)
+    if eng.resident:
+        eng.start()
+    prio = 1.0 if path == "inproc-heap" else 0.0
+    dag = _contract_dag(str(tmp_path / "flaky-ran"))
+    for name, fn, deps in dag:
+        eng.submit(name, fn, deps=deps, priority=prio)
+    if eng.resident:
+        assert eng.drain(timeout=60)
+        rep = eng.shutdown()
+    else:
+        rep = eng.run()
+    names = [name for name, _, _ in dag]
+    poisoned = {"bad1", "bad2"}
+    executed = [n for n in names if n not in poisoned]
+    ends = [e for e in rep.trace.events if e.event in (COMPLETED, FAILED)]
+    assert sorted(e.task for e in ends) == sorted(names)
+    assert sorted(seen) == sorted(names)
+    ran = [e for e in ends if e.worker is not None]
+    assert sorted(e.task for e in ran) == sorted(executed)
+    assert sum(s["done"] for s in eng.worker_stats().values()) == len(ran)
+    assert eng.exec_failed == 1 and eng.retries_total == 1
+    assert sorted(rep.results) == sorted(executed)
+    assert {n: r.value for n, r in rep.results.items() if r.ok} == \
+        {"a": 1, "b": 2, "c": 3, "d": 4, "flaky": 5}
+    assert not rep.results["bad"].ok and "boom" in rep.results["bad"].error
+    assert not rep.stalled
