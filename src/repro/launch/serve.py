@@ -59,12 +59,13 @@ def parse_args(argv=None):
 
 def build(args):
     """(cfg, model, params) at the size `args` asks for, with random
-    weights from `--seed`."""
+    weights from `--seed`, held as the model serves them
+    (`Model.serving_weights`): the float32 tree is not kept."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg, Options(q_block=64, kv_block=64, moe_group=64))
-    params = model.init(jax.random.PRNGKey(args.seed))
+    params = model.serving_weights(model.init(jax.random.PRNGKey(args.seed)))
     return cfg, model, params
 
 

@@ -9,6 +9,8 @@
 
 An MoE model's serving steps also return their MoE counts, summed over
 layers, where asked (`moe_counts=True`; `moe.N_COUNTS`).
+
+    weights = model.serving_weights(params)   # what the steps read, in bf16
 """
 from __future__ import annotations
 
@@ -20,6 +22,9 @@ import jax.numpy as jnp
 
 from repro.models import rwkv, transformer, whisper, zamba
 from repro.models.common import Options
+
+
+_to_bf16 = jax.jit(lambda ws: [w.astype(jnp.bfloat16) for w in ws])
 
 
 @dataclass
@@ -57,6 +62,24 @@ class Model:
             return self._mod.init_state(self.cfg, batch, abstract=abstract)
         return self._mod.init_cache(self.cfg, batch, max_len, dtype=dtype,
                                     abstract=abstract)
+
+    def serving_weights(self, params):
+        """`params` with the leaves that the family's steps read only as
+        bfloat16 (its module's `SERVED_BF16` names) cast to bfloat16, in
+        one jitted call; every other leaf, and one already in bfloat16,
+        is the caller's own object.  `params` itself where no leaf needs
+        the cast, as for a family that declares no names."""
+        names = getattr(self._mod, "SERVED_BF16", frozenset())
+        paths, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [leaf for _, leaf in paths]
+        pick = [i for i, (path, leaf) in enumerate(paths)
+                if getattr(path[-1], "key", None) in names
+                and leaf.dtype != jnp.bfloat16]
+        if not pick:
+            return params
+        for i, w in zip(pick, _to_bf16([leaves[i] for i in pick])):
+            leaves[i] = w
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def with_opts(self, **kw) -> "Model":
         return Model(self.cfg, self.opts.replace(**kw), self._mod)

@@ -21,6 +21,15 @@ from repro.models.common import (Options, activation, dense_init, embed_init,
                                  softcap)
 from repro.models.rope import apply_rope, mrope_angles, rope_angles
 
+# The leaves every step reads only as `.astype(<compute dtype>)` (the
+# `moe_gmm` kernel casts its expert blocks to its rows' dtype), so a
+# served model may hold them in bfloat16 (`Model.serving_weights`).  The
+# norm scales (`ln1`, `ln2`, `pn1`, `pn2`, `final_norm`, `kv_norm`) and the
+# MoE `router` are read in float32 and stay out.
+SERVED_BF16 = frozenset({"embed", "head", "wq", "wk", "wv", "wo", "bq", "bk",
+                         "bv", "w1", "w2", "w3", "ws1", "ws2", "ws3", "wdkv",
+                         "wuk", "wuv"})
+
 # ---------------------------------------------------------------------------
 # FFN
 # ---------------------------------------------------------------------------

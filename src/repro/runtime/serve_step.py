@@ -20,11 +20,24 @@ module when the steps are built, and the weights and the batch stay
 arguments, never constants of the kept programs.  `jit_cache_info()`
 counts the calls that built the steps or ran a new batch shape (misses,
 where JAX traces again) and the rest (hits); `jit_cache_clear()` drops
-every model's kept steps and resets the counts."""
+every model's kept steps and resets the counts.
+
+The steps read their weight matrices only in bfloat16, so
+`greedy_generate` hands them the caller's weights with those leaves cast
+once (`Model.serving_weights`, one jitted call, in the span
+`serve.cast_weights`) and keeps the cast copy on the model (its
+`_serve_weights`): one copy a model, under the step cache's generation
+and the identities of the caller's leaves, which the copy holds.  New
+weights replace the copy; it is freed with the model, and
+`jit_cache_clear()` drops it.  Where no leaf needs the cast (weights
+already in bfloat16) nothing is kept and the caller's tree goes to the
+steps as it is.  `weight_cast_info()` counts each call as one that cast,
+reused the copy, or bypassed it."""
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import deque
 
 import jax
@@ -113,6 +126,57 @@ def jit_cache_info() -> dict:
 
 def jit_cache_clear() -> None:
     _STEPS.clear()
+    _WEIGHTS.clear()
+
+
+class _WeightCache:
+    """Each model's served weights (`Model.serving_weights` of the
+    caller's tree) kept on the model, with the calls since the last
+    `clear()` that cast, reused the copy, or had nothing to cast."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.holders = weakref.WeakValueDictionary()    # id -> model
+        self.clear()
+
+    def clear(self) -> None:
+        with self.lock:
+            for model in list(self.holders.values()):
+                model._serve_weights = None
+            self.holders.clear()
+            self.casts = self.reuses = self.bypassed = 0
+
+    def get(self, model, params, generation: int):
+        """What the model's steps read for `params`."""
+        leaves = jax.tree_util.tree_leaves(params)
+        key = (id(model), generation, tuple(map(id, leaves)))
+        with self.lock:
+            kept = getattr(model, "_serve_weights", None)
+            if kept is not None and kept["key"] == key:
+                self.reuses += 1
+                return kept["weights"]
+            model._serve_weights = None     # the old copy goes first
+            with span("serve.cast_weights"):
+                weights = model.serving_weights(params)
+            if weights is params:
+                self.bypassed += 1
+                return params
+            model._serve_weights = {"key": key, "leaves": leaves,
+                                    "weights": weights}
+            self.holders[id(model)] = model
+            self.casts += 1
+            return weights
+
+
+_WEIGHTS = _WeightCache()
+
+
+def weight_cast_info() -> dict:
+    """`greedy_generate` calls, all models together, that cast the
+    weights, reused a kept copy, or had no leaf to cast."""
+    with _WEIGHTS.lock:
+        return {"casts": _WEIGHTS.casts, "reuses": _WEIGHTS.reuses,
+                "bypassed": _WEIGHTS.bypassed}
 
 
 def make_prefill_step(model, moe_counts: bool = False):
@@ -172,10 +236,12 @@ def prefill_into_cache(model, params, batch, cache_len: int, prefill,
 
 def greedy_generate(model, params, batch, max_new: int, cache_len: int):
     """Prefill, then greedy-decode max_new tokens with the model's kept
-    steps.  An MoE model's tokens come back as a host array, fetched
-    together with the batch's MoE counts (recorded for `moe_counts()`)."""
+    steps and served weights.  An MoE model's tokens come back as a host
+    array, fetched together with the batch's MoE counts (recorded for
+    `moe_counts()`)."""
     B, S = batch["tokens"].shape
     prefill, decode = _STEPS.get(model, (B, S, cache_len))
+    params = _WEIGHTS.get(model, params, _STEPS.generation)
     with span("serve.prefill"):
         tok, cache, *counts = prefill_into_cache(model, params, batch,
                                                  cache_len, prefill, decode)

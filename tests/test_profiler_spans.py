@@ -32,7 +32,8 @@ ENGINE_SPANS = ["engine.round", "engine.ingest", "engine.steal",
 CLIENT_SPANS = ["client.submit", "client.resolve"]
 FRONTEND_SPANS = ["frontend.idle", "frontend.wait", "frontend.dispatch",
                   "frontend.resolve"]
-SERVE_SPANS = ["serve.make_batch", "serve.prefill", "serve.decode"]
+SERVE_SPANS = ["serve.make_batch", "serve.cast_weights", "serve.prefill",
+               "serve.decode"]
 MESH_VERBS = ["map", "reduce", "sum", "scan", "group"]
 
 
